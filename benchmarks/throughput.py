@@ -992,6 +992,8 @@ if __name__ == "__main__":
                     help="CI-sized run: batch 2, K=4, few tokens, "
                          "parity only")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.smoke:
         metrics = run_smoke(args.mesh_devices, args.rules)
     elif args.mesh_devices > 1:

@@ -11,10 +11,12 @@ vocab blocks for the TPU target.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.models import layers as L
 
@@ -28,8 +30,10 @@ def alignment_spec(vocab: int, hidden: int = 64) -> Dict[str, L.P]:
     }
 
 
-def init_alignment(key, vocab: int, hidden: int = 64, dtype=jnp.float32):
-    return L.materialize(alignment_spec(vocab, hidden), key, dtype)
+def init_alignment(key, vocab: int, hidden: int = 64, dtype=jnp.float32,
+                   shardings=None):
+    return L.materialize(alignment_spec(vocab, hidden), key, dtype,
+                         shardings)
 
 
 def fusion_weight(mlp, p_slm: jax.Array, p_llm: jax.Array) -> jax.Array:
@@ -63,25 +67,32 @@ def fused_distribution(mlp, slm_logits: jax.Array, llm_logits: jax.Array,
 
 def fused_distribution_kernel(mlp, slm_logits: jax.Array,
                               llm_logits: jax.Array, arrived: jax.Array,
-                              block_b: int = 4
+                              block_b: int = 8, mesh=None
                               ) -> Tuple[jax.Array, jax.Array]:
     """Batched Sec. IV-C/IV-D step routed through the Pallas kernel.
 
     The fusion weight w (Eq. 14) needs the two probability vectors as
     MLP input, so those softmaxes are computed here either way; the
     Eq. 15 output distribution is then produced by the ``logit_fusion``
-    kernel, which re-derives both softmaxes from the raw logits in VMEM
-    rather than re-reading the (B, V) probability tensors from HBM —
-    a win at full 256k vocab on TPU, a wash at CPU-test scale.
-    arrived: (B,) bool; rows whose cloud logits missed τ get w=1
-    (per-row fallback).  Returns (P_out (B,V), w (B,))."""
+    kernel, which re-derives both softmaxes tile by tile from the raw
+    logits and per-row statistics instead of reading the (B, V)
+    probability tensors back (whether that is faster on the chip is not
+    measured).  arrived: (B,) bool; rows whose cloud logits missed τ get w=1
+    (per-row fallback).  On a ``mesh`` the logit rows are replicated
+    (the deployment's fusion contract) and every device runs the
+    kernel on them: a Pallas TPU kernel is not partitioned
+    automatically.  Returns (P_out (B,V), w (B,))."""
     from repro.kernels.logit_fusion.ops import fused_probs_masked
     p_slm = jax.nn.softmax(slm_logits.astype(jnp.float32), axis=-1)
     p_llm = jax.nn.softmax(llm_logits.astype(jnp.float32), axis=-1)
     w = fusion_weight(mlp, p_slm, p_llm)
     arrived = jnp.asarray(arrived, bool)
-    p = fused_probs_masked(slm_logits, llm_logits, w, arrived,
-                           block_b=block_b)
+    fuse = partial(fused_probs_masked, block_b=block_b)
+    if mesh is not None:
+        rep = PartitionSpec()
+        fuse = jax.shard_map(fuse, mesh=mesh, in_specs=(rep,) * 4,
+                             out_specs=rep, check_vma=False)
+    p = fuse(slm_logits, llm_logits, w, arrived)
     return p, jnp.where(arrived, w, 1.0)
 
 

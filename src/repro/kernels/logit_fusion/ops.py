@@ -20,20 +20,22 @@ def _on_cpu() -> bool:
 
 
 @partial(jax.jit, static_argnames=("block_b",))
-def fused_probs(slm_logits, llm_logits, w, block_b: int = 4):
+def fused_probs(slm_logits, llm_logits, w, block_b: int = 8):
     return fuse_logits(slm_logits, llm_logits, w, block_b=block_b,
                        interpret=_on_cpu())
 
 
 @partial(jax.jit, static_argnames=("block_b",))
 def fused_probs_masked(slm_logits, llm_logits, w, arrived,
-                       block_b: int = 4):
+                       block_b: int = 8):
     """Ragged-batch serving dispatch.
 
     slm/llm logits: (B, V) for any B >= 1; w: (B,); arrived: (B,) bool.
     B is padded up to a multiple of ``block_b`` (padded rows carry
     arrived=False and are dropped after the kernel), so the continuous-
-    decode engine can hand over whatever batch occupancy it has."""
+    decode engine can hand over whatever batch occupancy it has and the
+    kernel always sees row blocks that are multiples of the TPU's
+    8-row sublane tiling."""
     b, _ = slm_logits.shape
     bp = -(-b // block_b) * block_b
     pad = bp - b

@@ -69,11 +69,11 @@ def moe_lora_delta(x, a, b, gates, *, block_t: int = 128,
 def _moe_lora_slots_kernel(slots_ref, x_ref, a_ref, b_ref, o_ref):
     s = slots_ref[pl.program_id(0)]
     valid = (s >= 0).astype(jnp.float32)           # negative slot -> 0.0
-    x = x_ref[...].astype(jnp.float32)             # (1, k)
+    x = x_ref[0].astype(jnp.float32)               # (1, k)
     a = a_ref[0].astype(jnp.float32)               # (r, k)
     bmat = b_ref[0].astype(jnp.float32)            # (n, r)
     u = jnp.dot(x, a.T, preferred_element_type=jnp.float32)
-    o_ref[...] = (valid * jnp.dot(
+    o_ref[0] = (valid * jnp.dot(
         u, bmat.T, preferred_element_type=jnp.float32)).astype(o_ref.dtype)
 
 
@@ -87,7 +87,11 @@ def moe_lora_delta_slots(x, a, b, slots, *, interpret: bool = False):
     block-table gather), DMA-ing exactly one (r,k)+(n,r) expert per row.
     Negative slots (adapter-free rows) are clamped onto slot 0 for the
     fetch and masked to an exact 0.0 in-kernel, matching the all-zero
-    gate row of the dense path bit for bit."""
+    gate row of the dense path bit for bit.
+
+    Rows travel as (T, 1, k) / (T, 1, n) so each one-row block spans
+    the array's last two dims whole: a (1, k) block of a (T, k) array
+    breaks the TPU's 8-row tiling rule for any T > 1."""
     t, k = x.shape
     e, r, _ = a.shape
     n = b.shape[1]
@@ -99,15 +103,16 @@ def moe_lora_delta_slots(x, a, b, slots, *, interpret: bool = False):
         num_scalar_prefetch=1,
         grid=(t,),
         in_specs=[
-            pl.BlockSpec((1, k), lambda ti, slots_ref: (ti, 0)),
+            pl.BlockSpec((1, 1, k), lambda ti, slots_ref: (ti, 0, 0)),
             pl.BlockSpec((1, r, k), expert_map),
             pl.BlockSpec((1, n, r), expert_map),
         ],
-        out_specs=pl.BlockSpec((1, n), lambda ti, slots_ref: (ti, 0)),
+        out_specs=pl.BlockSpec((1, 1, n), lambda ti, slots_ref: (ti, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _moe_lora_slots_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, n), x.dtype),
         interpret=interpret,
-    )(slots.astype(jnp.int32), x, a, b)
+    )(slots.astype(jnp.int32), x.reshape(t, 1, k), a, b)
+    return out.reshape(t, n)

@@ -41,7 +41,7 @@ from repro.configs import (ASSIGNED_ARCHS, INPUT_SHAPES, get_config,  # noqa: E4
 from repro.core import fusion as FUS       # noqa: E402
 from repro.launch import analysis as AN    # noqa: E402
 from repro.launch import sharding as SH    # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import auto_mesh, make_production_mesh  # noqa: E402
 from repro.models.model import LM          # noqa: E402
 from repro.training import optimizer as OPT  # noqa: E402
 from repro.training import train_step as TS  # noqa: E402
@@ -398,7 +398,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     if mesh_shape:
         dims = tuple(int(x) for x in mesh_shape.split("x"))
         axes = ("pod", "data", "model")[-len(dims):]
-        mesh = jax.make_mesh(dims, axes)
+        mesh = auto_mesh(dims, axes)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size

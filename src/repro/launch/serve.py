@@ -1,9 +1,12 @@
 """Serving launcher.
 
-  * --local: run the real hybrid LLM-SLM engine on CPU (reduced configs)
-    with batched requests through the scheduler.  ``--mesh-devices N``
-    fakes an N-device host mesh (same XLA flag as the dry-run) and
-    shards the continuous-decode lanes over it.
+  * --local: run the real hybrid LLM-SLM engine in this process on
+    whatever devices JAX finds (reduced configs), with batched requests
+    through the scheduler.  Under ``JAX_PLATFORMS=cpu``
+    ``--mesh-devices N`` fakes an N-device host mesh (same XLA flag as
+    the dry-run) and shards the continuous-decode lanes over it.
+    ``chip_smoke.py`` at the repo root serves the pair at published
+    widths on a TPU.
   * default: lower the fused co-serving decode step (or a single-arch
     serve step) onto the production mesh.
 """
@@ -145,24 +148,36 @@ def main():
         import jax
         from repro.configs.floe_pair import needs_ring_cache, pair_configs
         from repro.core import fusion as FUS
+        from repro.launch.compile_cache import use_compile_cache
         from repro.models.model import LM
-        from repro.serving.deployment import ServingDeployment
+        from repro.serving.deployment import (ServingDeployment,
+                                              alignment_shardings,
+                                              model_param_shardings)
         from repro.serving.latency import FaultModel, LatencyModel
         from repro.serving.scheduler import (ContinuousBatchScheduler,
                                              Scheduler, summarize)
+        use_compile_cache()
         slm_cfg, llm_cfg = pair_configs(args.pair)
         slm = LM(slm_cfg, remat=False,
                  ring_cache=needs_ring_cache(slm_cfg))
         llm = LM(llm_cfg, remat=False)
-        sp = slm.init(jax.random.key(0))
-        lp = llm.init(jax.random.key(1))
-        mlp = FUS.init_alignment(jax.random.key(2), slm_cfg.vocab_size)
         mesh = None
+        shard = {"slm": None, "llm": None, "mlp": None}
         if args.mesh_devices > 1:
             from repro.launch.mesh import make_serving_mesh
             mesh = make_serving_mesh(args.mesh_devices,
                                      model_parallel=args.model_parallel)
             print(f"serving mesh: {dict(mesh.shape)}")
+            shard = {"slm": model_param_shardings(slm, mesh, args.rules),
+                     "llm": model_param_shardings(llm, mesh, args.rules),
+                     "mlp": alignment_shardings(slm_cfg.vocab_size, mesh,
+                                                args.rules)}
+        # params are drawn in place: on a mesh every leaf comes out
+        # already laid out the way the deployment keeps it
+        sp = slm.init(jax.random.key(0), shard["slm"])
+        lp = llm.init(jax.random.key(1), shard["llm"])
+        mlp = FUS.init_alignment(jax.random.key(2), slm_cfg.vocab_size,
+                                 shardings=shard["mlp"])
         fault = None
         if args.fault_rate > 0.0 or args.outage:
             period, olen = 0, 0

@@ -11,6 +11,7 @@ reshape at the call site) so one sharding rule covers every projection.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -51,13 +52,33 @@ def _init_array(spec: P, key, dtype) -> jax.Array:
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def materialize(specs: Tree, key: jax.Array, dtype) -> Tree:
-    """Spec tree -> params tree (single traversal, split keys per leaf)."""
+@functools.lru_cache(maxsize=None)
+def _materializer(treedef, leaves: Tuple[P, ...], dtype, shardings):
+    def build(key):
+        keys = jax.random.split(key, max(1, len(leaves)))
+        return jax.tree.unflatten(
+            treedef, [_init_array(s, k, dtype) for s, k in zip(leaves, keys)])
+    out = (None if shardings is None
+           else jax.tree.unflatten(treedef, list(shardings)))
+    return jax.jit(build, out_shardings=out)
+
+
+def materialize(specs: Tree, key: jax.Array, dtype,
+                shardings: Optional[Tree] = None) -> Tree:
+    """Spec tree -> params tree (single traversal, split keys per leaf).
+
+    The draws run as one jitted program, so each leaf's f32 draw, scale
+    and cast fuse and only the ``dtype`` result is ever stored.
+    ``shardings`` (a tree of shardings matching ``specs``) makes every
+    leaf come out already laid out on its devices: no whole leaf, and no
+    whole model, passes through one device on the way."""
     leaves, treedef = jax.tree.flatten(
         specs, is_leaf=lambda x: isinstance(x, P))
-    keys = jax.random.split(key, max(1, len(leaves)))
-    arrays = [_init_array(s, k, dtype) for s, k in zip(leaves, keys)]
-    return jax.tree.unflatten(treedef, arrays)
+    flat_sh = None
+    if shardings is not None:
+        flat_sh = tuple(treedef.flatten_up_to(shardings))
+    return _materializer(treedef, tuple(leaves), jnp.dtype(dtype),
+                         flat_sh)(key)
 
 
 def axes_tree(specs: Tree) -> Tree:

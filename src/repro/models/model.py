@@ -369,8 +369,10 @@ class LM:
                     "special": ((n_groups,), t)}
         return {"layers": ((cfg.num_layers,), t)}
 
-    def init(self, key) -> Dict[str, Any]:
-        return L.materialize(self.param_specs(), key, self.dtype)
+    def init(self, key, shardings=None) -> Dict[str, Any]:
+        """Random params; ``shardings`` (e.g. a serving deployment's
+        ``model_param_shardings``) draws each leaf in place."""
+        return L.materialize(self.param_specs(), key, self.dtype, shardings)
 
     def abstract_params(self):
         return L.abstract_params(self.param_specs(), self.dtype)

@@ -37,7 +37,6 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import fusion as FUS
@@ -67,6 +66,25 @@ def cache_batch_axes(lm, max_seq: int):
     return jax.tree.map(ax, c2, c3)
 
 
+def model_param_shardings(lm, mesh: Mesh, rules="inference"):
+    """Per-leaf NamedShardings of ``lm``'s params on a serving mesh —
+    the layout a deployment keeps them in.  Pass it to
+    ``lm.init(key, shardings=...)`` to draw the params in place."""
+    if isinstance(rules, str):
+        rules = SH.RULESETS[rules]
+    return SH.param_shardings(lm.param_axes(), lm.param_specs(), mesh,
+                              rules)
+
+
+def alignment_shardings(vocab: int, mesh: Mesh, rules="inference",
+                        hidden: int = 64):
+    """The alignment MLP's counterpart of ``model_param_shardings``."""
+    if isinstance(rules, str):
+        rules = SH.RULESETS[rules]
+    return SH.param_shardings(None, FUS.alignment_spec(vocab, hidden),
+                              mesh, rules)
+
+
 def _tree_bytes(tree, per_device: bool) -> int:
     """Bytes a tree occupies; per_device reads the placed arrays'
     addressable shards (replicated leaves count full size)."""
@@ -94,7 +112,7 @@ class ServingDeployment:
                  latency: Optional[LatencyModel] = None,
                  timeout_ms: float = 200.0, max_seq: int = 96,
                  sample_seed: int = 0, mesh: Optional[Mesh] = None,
-                 rules="inference", block_b: int = 4,
+                 rules="inference", block_b: int = 8,
                  page_size: int = 16, max_ctx: Optional[int] = None,
                  adapter_slots: int = 0,
                  adapter_rank: Optional[int] = None,
@@ -288,13 +306,15 @@ class ServingDeployment:
                                         None, pre),
                     3, psh_l, static_argnums=(4,))
 
+        # the fusion entry points take the alignment MLP as an argument
+        # (like every param tree above): a closed-over array would be
+        # baked into each program as a constant — 131 MB of w1 at the
+        # pair's V = 256 000, once per program that fuses
         if alignment_mlp is not None:
-            self.fuse = jax.jit(
-                lambda sl, ll, arrived: FUS.fused_distribution(
-                    self.mlp, sl, ll, arrived))
+            self.fuse = jax.jit(FUS.fused_distribution)
             self.fuse_batched = jax.jit(
-                lambda sl, ll, arrived: FUS.fused_distribution_kernel(
-                    self.mlp, sl, ll, arrived, block_b=block_b))
+                lambda mlp, sl, ll, arrived: FUS.fused_distribution_kernel(
+                    mlp, sl, ll, arrived, block_b=block_b, mesh=mesh))
         self.softmax_batched = jax.jit(
             lambda sl: jax.nn.softmax(sl.astype(jnp.float32), -1))
         self.argmax_batched = jax.jit(lambda p: jnp.argmax(p, -1))
@@ -334,15 +354,13 @@ class ServingDeployment:
     def _model_shardings(self, lm):
         if self.mesh is None or lm is None:
             return None
-        return SH.param_shardings(lm.param_axes(), lm.param_specs(),
-                                  self.mesh, self.rules)
+        return model_param_shardings(lm, self.mesh, self.rules)
 
     def _mlp_shardings(self, mlp):
         if self.mesh is None or mlp is None:
             return None
-        spec = FUS.alignment_spec(mlp["w1"].shape[0] // 2,
-                                  mlp["b1"].shape[0])
-        return SH.param_shardings(None, spec, self.mesh, self.rules)
+        return alignment_shardings(mlp["w1"].shape[0] // 2, self.mesh,
+                                   self.rules, mlp["b1"].shape[0])
 
     def _place(self, tree, shardings):
         if tree is None or shardings is None:
@@ -456,7 +474,7 @@ class ServingDeployment:
         the host syncs exactly once per K tokens, on the stacked traces.
 
         Lane caches, current logits and the per-row circuit-breaker
-        state are DONATED (argnums 4-9): the macro-step updates them in
+        state are DONATED (argnums 5-10): the macro-step updates them in
         place, invalidating any stale references a caller may hold.
         ``k`` and ``sample`` (whether any row draws categorically) are
         static — at most two traces per lane flavour per K.  Param args
@@ -477,7 +495,7 @@ class ServingDeployment:
         dep = self
         fault = self.fault if use_cloud else None
 
-        def impl(slm_params, llm_params, lora, gates,
+        def impl(slm_params, llm_params, mlp, lora, gates,
                  s_cache, l_cache, sl, ll, fails, cooldown,
                  rids, key_ids, steps, max_new, greedy, done,
                  k: int, sample: bool):
@@ -508,7 +526,7 @@ class ServingDeployment:
                                 edge, jnp.float32(dep.timeout_ms)), lat))
                     else:
                         arrived = OPS.cloud_arrival_mask(ok, active)
-                    probs, w = dep.fuse_batched(sl, ll, arrived)
+                    probs, w = dep.fuse_batched(mlp, sl, ll, arrived)
                 else:
                     probs = dep.softmax_batched(sl)
                     w = jnp.ones((b,), jnp.float32)
@@ -572,12 +590,13 @@ class ServingDeployment:
         kw: Dict[str, Any] = {}
         if self.mesh is not None:
             psh_l = self.llm_param_shardings if use_cloud else None
-            kw["in_shardings"] = ((self.slm_param_shardings, psh_l)
+            psh_m = self.mlp_shardings if use_cloud else None
+            kw["in_shardings"] = ((self.slm_param_shardings, psh_l, psh_m)
                                   + (None,) * 14)
         # k/sample are positional statics: pjit rejects kwargs when
         # in_shardings is given, so the engine passes them by position
-        return jax.jit(impl, static_argnums=(16, 17),
-                       donate_argnums=(4, 5, 6, 7, 8, 9), **kw)
+        return jax.jit(impl, static_argnums=(17, 18),
+                       donate_argnums=(5, 6, 7, 8, 9, 10), **kw)
 
     # ------------------------------------------------ speculative burst
     def _make_spec(self):
@@ -610,13 +629,13 @@ class ServingDeployment:
         under greedy accepts the whole window (zero rollback).
 
         Same donation/sharding discipline as ``_make_macro``: caches,
-        logits, ``lt`` and breaker state donated (argnums 4-9), params
+        logits, ``lt`` and breaker state donated (argnums 5-10), params
         pinned, carry pinned to the lane layout at both ends.  Traces:
         (sels (k,B), n_emit, c_sel, arrived, lat, w (k,B), lost)."""
         dep = self
         fault = self.fault
 
-        def impl(slm_params, llm_params, lora, gates,
+        def impl(slm_params, llm_params, mlp, lora, gates,
                  s_cache, l_cache, sl, lt, fails, cooldown,
                  rids, key_ids, steps, max_new, greedy, done,
                  k: int, sample: bool):
@@ -690,7 +709,8 @@ class ServingDeployment:
             # pair (sls[i], lls[i]) and selects with the baseline key
             sels, ws = [], []
             for i in range(k):
-                probs_i, w_i = dep.fuse_batched(sls[i], lls[i], arrived)
+                probs_i, w_i = dep.fuse_batched(mlp, sls[i], lls[i],
+                                                arrived)
                 sels.append(OPS.select_sample_fused(
                     probs_i, greedy, key_ids, steps + i,
                     seed=dep.sample_seed, sample=sample))
@@ -760,10 +780,11 @@ class ServingDeployment:
         kw: Dict[str, Any] = {}
         if self.mesh is not None:
             kw["in_shardings"] = ((self.slm_param_shardings,
-                                   self.llm_param_shardings)
+                                   self.llm_param_shardings,
+                                   self.mlp_shardings)
                                   + (None,) * 14)
-        return jax.jit(impl, static_argnums=(16, 17),
-                       donate_argnums=(4, 5, 6, 7, 8, 9), **kw)
+        return jax.jit(impl, static_argnums=(17, 18),
+                       donate_argnums=(5, 6, 7, 8, 9, 10), **kw)
 
     # ------------------------------------------------- cache row scatter
     def _make_insert(self, axes_tree):
@@ -816,10 +837,10 @@ class ServingDeployment:
                                 dst_loc - start, nb)
                 return f_loc.at[loc].set(t_loc, mode="drop")
 
-            fm = shard_map(body, mesh=mesh,
-                           in_specs=(P(*mspec), P(*rspec), P()),
-                           out_specs=P(*mspec),
-                           check_rep=False)(fm, taken, dst)
+            fm = jax.shard_map(body, mesh=mesh,
+                               in_specs=(P(*mspec), P(*rspec), P()),
+                               out_specs=P(*mspec),
+                               check_vma=False)(fm, taken, dst)
             return jnp.moveaxis(fm, 0, ax)
 
         def impl(full, row, src, dst):

@@ -86,6 +86,9 @@ def _reject_deployment_args(**named):
 @dataclass
 class GenStats:
     tokens: int = 0
+    # the emitted token ids, in order (the response text is their byte
+    # decode, which drops ids outside the byte range)
+    token_ids: List[int] = field(default_factory=list)
     cloud_tokens: int = 0
     fallback_tokens: int = 0
     private: bool = False
@@ -321,7 +324,7 @@ class HybridEngine:
         if use_cloud:
             l_logits, l_cache = dep.llm_prefill(self.llm_params, toks)
 
-        out_ids: List[int] = []
+        out_ids = stats.token_ids
         sl, ll = s_logits[:, 0], (l_logits[:, 0] if use_cloud else None)
         lat_row = ok_row = None
         if use_cloud and rid is not None:
@@ -360,7 +363,7 @@ class HybridEngine:
                         lat_ms, arrived = edge32, False
                     elif raw:
                         lat_ms, arrived = fb32, False
-                p_out, w = dep.fuse(sl, ll, jnp.asarray(arrived))
+                p_out, w = dep.fuse(dep.mlp, sl, ll, jnp.asarray(arrived))
                 stats.cloud_tokens += int(arrived)
                 stats.fallback_tokens += int(not arrived)
                 # one LLM round-trip per token on this path — degraded
@@ -406,7 +409,6 @@ class _Slot:
     max_new: int
     greedy: bool
     stats: GenStats
-    out_ids: List[int] = field(default_factory=list)
     key_id: Optional[int] = None     # per-request sampling seed override
     seq: int = -1                    # admission order (FIFO observable)
     # lazy-growth bookkeeping (paged lanes): the ORIGINAL prompt length
@@ -434,6 +436,10 @@ class _Slot:
     # be rewound to the one-behind protocol depth p-1 with the last
     # emitted token re-pended in ``lt`` before its next burst
     needs_spec_init: bool = False
+
+    @property
+    def out_ids(self) -> List[int]:
+        return self.stats.token_ids
 
 
 @dataclass
@@ -1122,7 +1128,7 @@ class _Lane:
             else:
                 degraded = np.zeros((b,), bool)
                 arrived = OPS.cloud_arrival_mask(ok, occ)
-            probs, w = dep.fuse_batched(self.sl, self.ll,
+            probs, w = dep.fuse_batched(dep.mlp, self.sl, self.ll,
                                         jnp.asarray(arrived))
         else:
             probs = dep.softmax_batched(self.sl)
@@ -1477,6 +1483,7 @@ class _Lane:
         fn = dep.macro_cloud if self.use_cloud else dep.macro_edge
         carry, traces = fn(
             eng.slm_params, eng.llm_params if self.use_cloud else None,
+            dep.mlp if self.use_cloud else None,
             eng.lora, self._decode_gates(),
             self.s_cache, self.l_cache, self.sl, self.ll,
             jnp.asarray(bfails), jnp.asarray(bcool),
@@ -1646,7 +1653,7 @@ class _Lane:
                                              degraded=degraded)
         else:
             arrived = OPS.cloud_arrival_mask(ok, occ)
-        probs, w = dep.fuse_batched(self.sl, self.ll,
+        probs, w = dep.fuse_batched(dep.mlp, self.sl, self.ll,
                                     jnp.asarray(arrived))
         nxt_greedy = np.asarray(dep.argmax_batched(probs))
         w_host = np.asarray(w)
@@ -1767,7 +1774,7 @@ class _Lane:
         bursts = []
         for _ in range(n_bursts):
             carry, traces = dep.spec_cloud(
-                eng.slm_params, eng.llm_params, eng.lora, gates,
+                eng.slm_params, eng.llm_params, dep.mlp, eng.lora, gates,
                 s_c, l_c, sl, lt, fails_d, cool_d,
                 rids_d, keys_d, steps_d, maxn_d, greedy_d, done_d,
                 k, sample)
@@ -1901,7 +1908,7 @@ class BatchedHybridEngine(HybridEngine):
                  latency: Optional[LatencyModel] = None,
                  timeout_ms: float = 200.0, max_seq: int = 96,
                  sample_seed: int = 0, batch_size: int = 8,
-                 edge_batch_size: Optional[int] = None, block_b: int = 4,
+                 edge_batch_size: Optional[int] = None, block_b: int = 8,
                  packed_prefill: bool = True, prefill_chunk: int = 16,
                  mesh=None, rules="inference", macro_k: int = 8,
                  paged: bool = True, pool_pages: Optional[int] = None,
@@ -1926,7 +1933,7 @@ class BatchedHybridEngine(HybridEngine):
                 expert_bank=(expert_bank, None), latency=(latency, None),
                 timeout_ms=(timeout_ms, 200.0), max_seq=(max_seq, 96),
                 sample_seed=(sample_seed, 0), mesh=(mesh, None),
-                rules=(rules, "inference"), block_b=(block_b, 4))
+                rules=(rules, "inference"), block_b=(block_b, 8))
         if deployment.llm is None:
             raise ValueError(
                 "BatchedHybridEngine needs a hybrid (SLM+LLM) deployment;"

@@ -6,7 +6,6 @@ direct fully-unrolled compile of a deeper config.
 """
 import dataclasses
 
-import jax
 import pytest
 
 from repro.configs import INPUT_SHAPES, get_config
@@ -19,7 +18,8 @@ def tiny_shape():
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    return make_host_mesh()
 
 
 def test_probe_extrapolation_matches_unrolled(tiny_shape, monkeypatch):

@@ -64,10 +64,16 @@ PROMPTS = [
 # before any injected fault
 JITTERY = dict(rtt_ms=160, jitter_ms=40.0, cloud_compute_ms=20, seed=7)
 JITTERY_EDGE = 65.0          # LatencyModel default edge_compute_ms
-# lossy + bursty weather that reliably trips breakers within a few
-# tokens (outage_len >= breaker_n) and still lets probes succeed
-CHAOS = dict(loss_rate=0.25, outage_period=10, outage_len=3, seed=3,
+# lossy + bursty weather that trips breakers by construction and still
+# lets probes succeed: outage_len >= breaker_n, so each full outage
+# window alone trips an attempting row.  Whatever the seeded phase, the
+# first full window starts by step outage_period - 1, so a request of
+# CHAOS_TRIP_TOKENS tokens trips, and one of CHAOS_DEGRADE_TOKENS also
+# decodes at least one token degraded
+CHAOS = dict(loss_rate=0.25, outage_period=5, outage_len=3, seed=3,
              breaker_n=2, breaker_m=3)
+CHAOS_TRIP_TOKENS = CHAOS["outage_period"] + CHAOS["breaker_n"] - 1
+CHAOS_DEGRADE_TOKENS = CHAOS_TRIP_TOKENS + 1
 
 
 @pytest.fixture(scope="module")
@@ -178,13 +184,18 @@ def test_fault_parity_across_paths(parts):
     simulated clock — because loss draws are counter-based and the host
     breaker mirror replays the device carry's recurrence exactly."""
     dep = _dep(parts, fault=FaultModel(**CHAOS))
-    ref, eng = _run_batched(dep, macro_k=0, n_tokens=8)
-    _assert_bitexact(ref, _run_batched(dep, macro_k=1, n_tokens=8)[0])
-    _assert_bitexact(ref, _run_batched(dep, macro_k=4, n_tokens=8)[0])
-    seq, _ = _run_sequential(dep, n_tokens=8)
+    n_tokens = 8
+    assert n_tokens >= CHAOS_DEGRADE_TOKENS
+    ref, eng = _run_batched(dep, macro_k=0, n_tokens=n_tokens)
+    _assert_bitexact(ref, _run_batched(dep, macro_k=1,
+                                       n_tokens=n_tokens)[0])
+    _assert_bitexact(ref, _run_batched(dep, macro_k=4,
+                                       n_tokens=n_tokens)[0])
+    seq, _ = _run_sequential(dep, n_tokens=n_tokens)
     _assert_bitexact(ref, seq)
-    # the weather actually bit: some cloud attempt was injected-lost
-    # and some token decoded under a tripped breaker
+    # the weather actually bit (by construction, see CHAOS): some cloud
+    # attempt was injected-lost and some token decoded under a tripped
+    # breaker
     assert sum(r.cloud_lost for r in ref) >= 1
     assert sum(r.degraded_tokens for r in ref) >= 1
     assert eng.health_stats()["breaker_trips"] >= 1
@@ -376,6 +387,7 @@ def _run_mesh_fault_parity(n_tokens=6):
     sp, lp = slm.init(jax.random.key(0)), llm.init(jax.random.key(1))
     mlp = FUS.init_alignment(jax.random.key(2), scfg.vocab_size)
     parts_ = (slm, sp, llm, lp, mlp)
+    assert n_tokens >= CHAOS_TRIP_TOKENS
     fault = FaultModel(**CHAOS)
     mesh = make_serving_mesh(min(len(jax.devices()), 8))
     ref, _ = _run_batched(_dep(parts_, fault=fault), 0, n_tokens)

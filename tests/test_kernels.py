@@ -271,6 +271,21 @@ def test_logit_fusion_sweep(b, v, dtype):
     np.testing.assert_allclose(np.asarray(out.sum(-1)), 1.0, atol=1e-3)
 
 
+@pytest.mark.parametrize("v,block_v", [(4096, 1024), (1000, 384)])
+def test_logit_fusion_vocab_tiles(v, block_v):
+    """Rows wider than one vocab tile: lane-aligned tiles that divide V,
+    and a V no multiple of 128 divides (padded with -inf logits)."""
+    ks = jax.random.split(jax.random.key(9), 3)
+    sl = jax.random.normal(ks[0], (8, v)) * 3.0
+    ll = jax.random.normal(ks[1], (8, v)) * 3.0
+    w = jax.nn.sigmoid(jax.random.normal(ks[2], (8,)))
+    arrived = jnp.arange(8) % 3 != 0
+    out = fuse_logits(sl, ll, w, arrived=arrived, block_v=block_v,
+                      interpret=True)
+    ref = fuse_logits_ref(sl, ll, w, arrived)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
 @pytest.mark.parametrize("b", [1, 3, 5, 8])
 def test_logit_fusion_ragged_batch(b):
     """Ragged serving batches: ops wrapper pads B up to a block_b

@@ -65,7 +65,7 @@ def _engine(parts, macro_k, latency_kw=JITTERY, flat_fusion=False, **kw):
                               edge_batch_size=2, macro_k=macro_k, **kw)
     if flat_fusion:
         v = slm.cfg.vocab_size
-        eng.dep.fuse_batched = lambda sl, ll, arrived: (
+        eng.dep.fuse_batched = lambda mlp, sl, ll, arrived: (
             jnp.full((sl.shape[0], v), 1.0 / v),
             jnp.ones((sl.shape[0],)))
     return eng

@@ -244,12 +244,12 @@ def test_batched_sampling_matches_sequential_stream(engine_parts):
     seqe = HybridEngine(slm, sp, llm, lp, mlp, max_seq=48,
                         latency=LatencyModel(rtt_ms=10, jitter_ms=0),
                         timeout_ms=200.0)
-    seqe.dep.fuse = lambda sl, ll, arrived: (jnp.full((1, v), 1.0 / v),
+    seqe.dep.fuse = lambda mlp, sl, ll, arrived: (jnp.full((1, v), 1.0 / v),
                                           jnp.ones((1,)))
     bat = BatchedHybridEngine(slm, sp, llm, lp, mlp, max_seq=48,
                               latency=LatencyModel(rtt_ms=10, jitter_ms=0),
                               timeout_ms=200.0, batch_size=4)
-    bat.dep.fuse_batched = lambda sl, ll, arrived: (
+    bat.dep.fuse_batched = lambda mlp, sl, ll, arrived: (
         jnp.full((sl.shape[0], v), 1.0 / v), jnp.ones((sl.shape[0],)))
     prompts = [p for p in PARITY_PROMPTS if not bat.detector.detect(p)]
     want = [seqe.generate(p, 6, greedy=False, rid=i)[0]
@@ -317,13 +317,13 @@ def test_scheduler_nongreedy_bitexact(engine_parts):
     seqe = HybridEngine(slm, sp, llm, lp, mlp, max_seq=48,
                         latency=LatencyModel(rtt_ms=10, jitter_ms=0),
                         timeout_ms=200.0)
-    seqe.dep.fuse = lambda sl, ll, arrived: (jnp.full((1, v), 1.0 / v),
+    seqe.dep.fuse = lambda mlp, sl, ll, arrived: (jnp.full((1, v), 1.0 / v),
                                           jnp.ones((1,)))
     bat = BatchedHybridEngine(slm, sp, llm, lp, mlp, max_seq=48,
                               latency=LatencyModel(rtt_ms=10, jitter_ms=0),
                               timeout_ms=200.0, batch_size=4,
                               edge_batch_size=2)
-    bat.dep.fuse_batched = lambda sl, ll, arrived: (
+    bat.dep.fuse_batched = lambda mlp, sl, ll, arrived: (
         jnp.full((sl.shape[0], v), 1.0 / v), jnp.ones((sl.shape[0],)))
     s1, s2 = Scheduler(seqe), ContinuousBatchScheduler(bat)
     for i, p in enumerate(PARITY_PROMPTS):
@@ -425,7 +425,7 @@ def test_sampling_keys_differ_across_requests(engine_parts):
     eng = HybridEngine(slm, sp, llm, lp, mlp, max_seq=48,
                        latency=LatencyModel(rtt_ms=10, jitter_ms=0))
     v = slm.cfg.vocab_size
-    eng.dep.fuse = lambda sl, ll, arrived: (jnp.full((1, v), 1.0 / v),
+    eng.dep.fuse = lambda mlp, sl, ll, arrived: (jnp.full((1, v), 1.0 / v),
                                             jnp.ones((1,)))
     outs = {eng.generate("tell me a fun fact", 8, greedy=False, rid=rid)[0]
             for rid in range(4)}

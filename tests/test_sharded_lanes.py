@@ -117,6 +117,11 @@ def _assert_layout(eng):
             want = eng.dep.lane_shardings(lm, lane.batch)
         spanned = batch_sharded = wide_sharded = False
         for leaf, sh in zip(jax.tree.leaves(cache), jax.tree.leaves(want)):
+            if leaf.size == 0:
+                # a zero-size leaf (an empty layer group) holds no bytes
+                # on any device, so it has no layout to check: jit
+                # outputs give it the trivial PartitionSpec()
+                continue
             assert leaf.sharding.is_equivalent_to(sh, leaf.ndim), \
                 (leaf.shape, leaf.sharding, sh)
             spec = sh.spec

@@ -66,7 +66,13 @@ PROMPTS = [
 # per-burst arrival draw equals the per-token draws it replaces and the
 # reconciliation is EXACT (see docs/serving.md "speculative decode")
 CALM = dict(rtt_ms=50.0, jitter_ms=5.0, cloud_compute_ms=20.0, seed=7)
-CHAOS = dict(loss_rate=0.25, outage_period=10, outage_len=3, seed=3,
+# faulty weather that degrades a k=2 burst by construction: weather is
+# drawn once per burst, keyed at its first step, and bursts advance at
+# most k steps, so a window of outage_len >= breaker_n * k steps holds
+# breaker_n consecutive burst keys and trips the row; the first full
+# window starts by step outage_period - 1, and the burst after the trip
+# starts at most (breaker_n + 1) * k - 1 steps into it
+CHAOS = dict(loss_rate=0.25, outage_period=5, outage_len=4, seed=3,
              breaker_n=2, breaker_m=3)
 N_TOK = 10
 
@@ -93,7 +99,7 @@ def _dep(parts, fault=None, **kw):
                              fault=fault, **kw)
 
 
-def _skew_fusion(sl, ll, arrived):
+def _skew_fusion(mlp, sl, ll, arrived):
     """Deterministic pure function of the logits whose greedy choice
     sometimes diverges from argmax(sl): the reduced random pair agrees
     on every position naturally, so without this stub the reject /
@@ -342,8 +348,11 @@ def test_rollback_leaves_state_as_never_drafted(skew_dep):
 
 def test_spec_under_faults_degrades_to_pure_slm(parts):
     d = _dep(parts, fault=FaultModel(**CHAOS))
-    a = _run(d, 2, 8)
-    b = _run(d, 2, 8)
+    k = 2
+    assert CHAOS["outage_len"] >= CHAOS["breaker_n"] * k
+    assert N_TOK >= CHAOS["outage_period"] + (CHAOS["breaker_n"] + 1) * k - 1
+    a = _run(d, k, 8)
+    b = _run(d, k, 8)
     for ra, rb in zip(a, b):               # burst replay is a pure
         assert ra.text == rb.text          # function of (rid, step)
         assert ra.stats.latency_ms == rb.stats.latency_ms
