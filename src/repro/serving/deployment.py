@@ -33,6 +33,7 @@ and locked in by tests/test_deployment.py.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -48,6 +49,17 @@ from repro.models import attention as ATT
 from repro.serving import paging as PAG
 from repro.serving import latency as LAT
 from repro.serving.latency import FaultModel, LatencyModel
+
+
+def jit_entry(name: str, fn, **kw):
+    """``jax.jit`` of ``fn`` under the entry point's own name: the
+    program compiles to the XLA module ``jit_<name>``, so a profile
+    names it without lining executions up with calls."""
+    @functools.wraps(fn)
+    def entry(*args, **kwargs):
+        return fn(*args, **kwargs)
+    entry.__name__ = entry.__qualname__ = name
+    return jax.jit(entry, **kw)
 
 
 def cache_batch_axes(lm, max_seq: int):
@@ -194,8 +206,9 @@ class ServingDeployment:
                     in_shardings=(self.adapter_bank_shardings, None,
                                   None),
                     out_shardings=self.adapter_bank_shardings)
-            self.write_adapter_slot = jax.jit(
-                LORA.write_slot, donate_argnums=(0,), **kw)
+            self.write_adapter_slot = jit_entry(
+                "write_adapter_slot", LORA.write_slot, donate_argnums=(0,),
+                **kw)
 
         # ---- lane-cache layout (structural batch-axis discovery)
         self.slm_axes = cache_batch_axes(slm, max_seq)
@@ -218,43 +231,51 @@ class ServingDeployment:
         rep = (NamedSharding(mesh, P()) if mesh is not None else None)
         psh_s, psh_l = self.slm_param_shardings, self.llm_param_shardings
 
-        def jit(fn, n_extra, params_shardings, out=None, **kw):
-            """jit with the params arg (position 0) pinned to its
+        def jit(name, fn, n_extra, params_shardings, out=None, **kw):
+            """jit_entry with the params arg (position 0) pinned to its
             placed layout when a mesh is present; remaining args and
             outputs are unconstrained unless ``out`` pins them."""
             if mesh is None or params_shardings is None:
-                return jax.jit(fn, **kw)
-            return jax.jit(
-                fn, in_shardings=(params_shardings,) + (None,) * n_extra,
+                return jit_entry(name, fn, **kw)
+            return jit_entry(
+                name, fn,
+                in_shardings=(params_shardings,) + (None,) * n_extra,
                 out_shardings=out, **kw)
 
         self.slm_prefill = jit(
+            "slm_prefill",
             lambda p, toks, lora, g: slm.prefill(
                 p, {"tokens": toks}, max_seq, lora=lora, gates=g),
             3, psh_s)
         self.slm_prefill_packed = jit(
+            "slm_prefill_packed",
             lambda p, toks, lens, lora, g: self._lane_out(
                 slm.prefill_packed(p, {"tokens": toks}, lens, max_seq,
                                    lora=lora, gates=g), self.slm_axes),
             4, psh_s, out=(rep, None) if mesh is not None else None)
         self.slm_decode = jit(
+            "slm_decode",
             lambda p, c, t, lora, g: self._lane_out(
                 slm.decode_step(p, c, t, lora, g),
                 self._axes_like(c, "slm")),
             4, psh_s, out=(rep, None) if mesh is not None else None)
-        self.insert_slm = self._make_insert(self.slm_axes)
-        self.insert_row = jax.jit(
+        self.insert_slm = self._make_insert("insert_slm", self.slm_axes)
+        self.insert_row = jit_entry(
+            "insert_row",
             lambda full, rows, src, dst: full.at[dst].set(rows[src]))
         if self._pageable(slm):
-            self.slm_page_rows = jax.jit(
+            self.slm_page_rows = jit_entry(
+                "slm_page_rows",
                 lambda c: slm.cache_to_page_rows(c, page_size, max_seq))
             self.insert_slm_paged = self._make_insert_paged(slm)
             self.insert_slm_prefix = self._make_insert_prefix(slm)
             self.slm_build_prefix = jit(
+                "slm_build_prefix",
                 lambda p, toks, lora, g: slm.build_prefix(
                     p, toks, lora=lora, gates=g),
                 3, psh_s)
             self.slm_prefill_suffix = jit(
+                "slm_prefill_suffix",
                 lambda p, toks, lens, hist, lora, g, pre, share:
                     self._suffix_out(slm, p, toks, lens, hist, lora, g,
                                      pre, share),
@@ -262,45 +283,56 @@ class ServingDeployment:
             # chunked long-prompt prefill: one dispatch per middle
             # chunk — suffix prefill + page freeze + history extension
             self.slm_prefill_chunk = jit(
+                "slm_prefill_chunk",
                 lambda p, toks, lens, hist, lora, g, pre:
                     self._chunk_out(slm, p, toks, lens, hist, lora, g,
                                     pre),
                 5, psh_s, static_argnums=(6,))
-        self.free_paged_rows = jax.jit(self._free_paged_rows_impl)
+        self.free_paged_rows = jit_entry("free_paged_rows",
+                                         self._free_paged_rows_impl)
         # lazy-growth helpers: batched block-table page mapping and
         # row-pos park/unpark (pos = FREED_POS drops every paged write)
-        self.grow_block_pages = jax.jit(self._grow_block_impl)
-        self.set_row_pos = jax.jit(
+        self.grow_block_pages = jit_entry("grow_block_pages",
+                                          self._grow_block_impl)
+        self.set_row_pos = jit_entry(
+            "set_row_pos",
             lambda c, idx, val: dict(
                 c, pos=c["pos"].at[idx].set(val, mode="drop")))
         if llm is not None:
             self.llm_prefill = jit(
+                "llm_prefill",
                 lambda p, toks: llm.prefill(p, {"tokens": toks}, max_seq),
                 1, psh_l)
             self.llm_prefill_packed = jit(
+                "llm_prefill_packed",
                 lambda p, toks, lens: self._lane_out(
                     llm.prefill_packed(p, {"tokens": toks}, lens, max_seq),
                     self.llm_axes),
                 2, psh_l, out=(rep, None) if mesh is not None else None)
             self.llm_decode = jit(
+                "llm_decode",
                 lambda p, c, t: self._lane_out(
                     llm.decode_step(p, c, t), self._axes_like(c, "llm")),
                 2, psh_l, out=(rep, None) if mesh is not None else None)
-            self.insert_llm = self._make_insert(self.llm_axes)
+            self.insert_llm = self._make_insert("insert_llm", self.llm_axes)
             if self._pageable(llm):
-                self.llm_page_rows = jax.jit(
+                self.llm_page_rows = jit_entry(
+                    "llm_page_rows",
                     lambda c: llm.cache_to_page_rows(c, page_size,
                                                      max_seq))
                 self.insert_llm_paged = self._make_insert_paged(llm)
                 self.insert_llm_prefix = self._make_insert_prefix(llm)
                 self.llm_build_prefix = jit(
+                    "llm_build_prefix",
                     lambda p, toks: llm.build_prefix(p, toks), 1, psh_l)
                 self.llm_prefill_suffix = jit(
+                    "llm_prefill_suffix",
                     lambda p, toks, lens, hist, pre, share:
                         self._suffix_out(llm, p, toks, lens, hist, None,
                                          None, pre, share),
                     3, psh_l, static_argnums=(4, 5))
                 self.llm_prefill_chunk = jit(
+                    "llm_prefill_chunk",
                     lambda p, toks, lens, hist, pre:
                         self._chunk_out(llm, p, toks, lens, hist, None,
                                         None, pre),
@@ -311,32 +343,41 @@ class ServingDeployment:
         # baked into each program as a constant — 131 MB of w1 at the
         # pair's V = 256 000, once per program that fuses
         if alignment_mlp is not None:
-            self.fuse = jax.jit(FUS.fused_distribution)
-            self.fuse_batched = jax.jit(
+            self.fuse = jit_entry("fuse", FUS.fused_distribution)
+            self.fuse_batched = jit_entry(
+                "fuse_batched",
                 lambda mlp, sl, ll, arrived: FUS.fused_distribution_kernel(
                     mlp, sl, ll, arrived, block_b=block_b, mesh=mesh))
-        self.softmax_batched = jax.jit(
+        self.softmax_batched = jit_entry(
+            "softmax_batched",
             lambda sl: jax.nn.softmax(sl.astype(jnp.float32), -1))
-        self.argmax_batched = jax.jit(lambda p: jnp.argmax(p, -1))
-        self.sample_batched = lambda probs, rids, steps: OPS.sample_fused(
-            probs, rids, steps, seed=self.sample_seed)
+        self.argmax_batched = jit_entry("argmax_batched",
+                                        lambda p: jnp.argmax(p, -1))
+        self.sample_batched = jit_entry(
+            "sample_batched",
+            lambda probs, rids, steps: OPS.sample_fused(
+                probs, rids, steps, seed=self.sample_seed))
         # counter-based network weather, one vectorized draw per call:
         # lat_batched serves a whole batch row set (per-step AND inside
         # the macro scan — both see bitwise-identical weather),
         # lat_request a whole request's steps for the sequential engine
-        self.lat_batched = jax.jit(
+        self.lat_batched = jit_entry(
+            "lat_batched",
             lambda rids, steps: self.latency.token_latency_device(
                 self.timeout_ms, rids, steps))
-        self.lat_request = jax.jit(
+        self.lat_request = jit_entry(
+            "lat_request",
             lambda rid, steps: self.latency.token_latency_device(
                 self.timeout_ms, jnp.full_like(steps, rid), steps))
         # counter-based fault weather, same parity discipline: one
         # vectorized (lost, outage) draw shared bitwise by the per-step
         # path, the macro scan and the sequential engine's prefetch
         if self.fault is not None:
-            self.fault_batched = jax.jit(
+            self.fault_batched = jit_entry(
+                "fault_batched",
                 lambda rids, steps: self.fault.faults_device(rids, steps))
-            self.fault_request = jax.jit(
+            self.fault_request = jit_entry(
+                "fault_request",
                 lambda rid, steps: self.fault.faults_device(
                     jnp.full_like(steps, rid), steps))
         else:
@@ -595,8 +636,9 @@ class ServingDeployment:
                                   + (None,) * 14)
         # k/sample are positional statics: pjit rejects kwargs when
         # in_shardings is given, so the engine passes them by position
-        return jax.jit(impl, static_argnums=(17, 18),
-                       donate_argnums=(5, 6, 7, 8, 9, 10), **kw)
+        return jit_entry("macro_cloud" if use_cloud else "macro_edge", impl,
+                         static_argnums=(17, 18),
+                         donate_argnums=(5, 6, 7, 8, 9, 10), **kw)
 
     # ------------------------------------------------ speculative burst
     def _make_spec(self):
@@ -783,11 +825,11 @@ class ServingDeployment:
                                    self.llm_param_shardings,
                                    self.mlp_shardings)
                                   + (None,) * 14)
-        return jax.jit(impl, static_argnums=(17, 18),
-                       donate_argnums=(5, 6, 7, 8, 9, 10), **kw)
+        return jit_entry("spec_cloud", impl, static_argnums=(17, 18),
+                         donate_argnums=(5, 6, 7, 8, 9, 10), **kw)
 
     # ------------------------------------------------- cache row scatter
-    def _make_insert(self, axes_tree):
+    def _make_insert(self, name: str, axes_tree):
         """Jitted (full, row_cache, src_rows, dst_slots) scatter of
         prefilled cache rows into a stacked lane cache — ALL rows of an
         admission burst in one fused update (a per-row loop would copy
@@ -863,7 +905,7 @@ class ServingDeployment:
                     res = sharded(f, r, ax, src, dst, spec)
                 out.append(res)
             return jax.tree.unflatten(fdef, out)
-        return jax.jit(impl)
+        return jit_entry(name, impl)
 
     # ------------------------------------------------------ paged layout
     # Paged lane caches keep the dense leaf tree with each (batch, seq)
@@ -1062,7 +1104,9 @@ class ServingDeployment:
                 new["local"] = rep(full["local"].at[dst].set(
                     local_rows, mode="drop"))
             return new
-        return jax.jit(impl)
+        return jit_entry(
+            "insert_slm_paged" if lm is self.slm else "insert_llm_paged",
+            impl)
 
     def _make_insert_prefix(self, lm):
         """Jitted COW prefix-page write: (full, content, pids) scatters
@@ -1099,4 +1143,6 @@ class ServingDeployment:
                         **{n: scat(full[kind][n], kv[n], pids)
                            for n in ("k", "v")})
             return out
-        return jax.jit(impl)
+        return jit_entry(
+            "insert_slm_prefix" if lm is self.slm else "insert_llm_prefix",
+            impl)

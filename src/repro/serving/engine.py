@@ -19,6 +19,7 @@ private deployment internally.
 """
 from __future__ import annotations
 
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -36,6 +37,21 @@ from repro.serving import latency as LAT
 from repro.serving import paging as PAG
 from repro.serving.deployment import ServingDeployment
 from repro.serving.latency import LatencyModel
+from repro.serving.telemetry import Telemetry, clock_ns
+
+# counter names of ``engine.telemetry``: health_stats() and
+# growth_stats() are views over the first two groups
+HEALTH_KEYS = ("losses", "outage_steps", "breaker_trips",
+               "breaker_recoveries", "degraded_tokens", "cancellations")
+GROWTH_KEYS = ("grown_pages", "parks", "evictions", "forced")
+# soft refusals of add_requests, by reason: no free row, free pages
+# short, held behind an earlier refusal of the same lane (FIFO), held
+# while the lane re-admits evicted rows, no adapter slot free
+REFUSAL_KEYS = ("refused_lane_full", "refused_pages", "refused_fifo",
+                "refused_evictq", "refused_adapter")
+BOUNDARY_KEYS = ("offers", "admitted", *REFUSAL_KEYS, "hard_rejects",
+                 "boundaries_cloud", "boundaries_edge", "tokens_emitted",
+                 "prefill_chunks")
 
 _BANK_NEEDS_GATING = (
     "expert_bank is set but nothing gates it — the bank would be "
@@ -173,12 +189,11 @@ class HybridEngine:
         self.timeout_ms = deployment.timeout_ms
         self.max_seq = deployment.max_seq
         self.sample_seed = deployment.sample_seed
-        # injected cloud-link faults (None = the fault-free oracle) and
-        # the engine-wide degradation telemetry behind health_stats()
+        # injected cloud-link faults (None = the fault-free oracle)
         self.fault = deployment.fault
-        self._health = dict(losses=0, outage_steps=0, breaker_trips=0,
-                            breaker_recoveries=0, degraded_tokens=0,
-                            cancellations=0)
+        # the engine's one record of spans, counters and request times
+        # (serving/telemetry.py); health_stats() is a view over it
+        self.telemetry = Telemetry(HEALTH_KEYS)
         # per-user adapter serving: the engine's OWN refcounted slot
         # cache over a fresh device bank (write_adapter_slot donates,
         # so caches never share buffers)
@@ -218,7 +233,7 @@ class HybridEngine:
         steps seen by cloud attempts, circuit-breaker trips and
         recoveries, tokens served SLM-only under a tripped breaker, and
         deadline cancellations.  All zero on a fault-free engine."""
-        return dict(self._health)
+        return self.telemetry.view(HEALTH_KEYS)
 
     def _fault_f32(self) -> Tuple[float, float]:
         """(edge, fallback) latencies in the float32 quantization the
@@ -227,12 +242,14 @@ class HybridEngine:
         edge = float(np.float32(self.latency.edge_compute_ms))
         return edge, max(edge, float(np.float32(self.timeout_ms)))
 
-    def _mirror_breaker(self, slot: "_Slot", lost: bool, step: int):
+    def _mirror_breaker(self, slot: "_Slot", lost: bool, step: int,
+                        tally: Counter):
         """Advance a slot's HOST breaker mirror by one attempted token
-        and fold the outcome into the health counters.  The mirror runs
-        the same ``breaker_step`` recurrence on the same weather the
-        device carry integrates inside the macro scan, so it stays
-        bit-equal to the device state at every boundary — the device
+        and fold the outcome into ``tally``, the caller's health counts
+        (merged into the telemetry once per boundary or request).  The
+        mirror runs the same ``breaker_step`` recurrence on the same
+        weather the device carry integrates inside the macro scan, so it
+        stays bit-equal to the device state at every boundary — the device
         state is authoritative DURING a scan, the mirror between scans
         (admission resets, eviction checkpoints, telemetry).
 
@@ -243,13 +260,12 @@ class HybridEngine:
         (slot.bfails, slot.bcool, degraded, attempt, _fail, trip,
          recover) = LAT.breaker_step(slot.bfails, slot.bcool, True, raw,
                                      fault.breaker_n, fault.breaker_m)
-        h = self._health
         if attempt:
-            h["losses"] += int(bool(lost))
-            h["outage_steps"] += int(outage)
-        h["breaker_trips"] += int(trip)
-        h["breaker_recoveries"] += int(recover)
-        h["degraded_tokens"] += int(degraded)
+            tally["losses"] += int(bool(lost))
+            tally["outage_steps"] += int(outage)
+        tally["breaker_trips"] += int(trip)
+        tally["breaker_recoveries"] += int(recover)
+        tally["degraded_tokens"] += int(degraded)
         st = slot.stats
         st.degraded_tokens += int(degraded)
         st.cloud_lost += int(attempt and raw)
@@ -343,10 +359,11 @@ class HybridEngine:
             lost_row = np.asarray(lost_d)
         slot = _Slot(rid or 0, max_new_tokens, greedy, stats)
         edge32, fb32 = self._fault_f32()
+        health = Counter()
         for _ in range(max_new_tokens):
             if deadline_ms is not None and stats.clock_ms >= deadline_ms:
                 stats.cancelled = True
-                self._health["cancellations"] += 1
+                health["cancellations"] += 1
                 break
             if use_cloud:
                 if lat_row is not None:
@@ -358,7 +375,8 @@ class HybridEngine:
                 degraded = False
                 if lost_row is not None:
                     degraded, raw = self._mirror_breaker(
-                        slot, bool(lost_row[len(out_ids)]), len(out_ids))
+                        slot, bool(lost_row[len(out_ids)]), len(out_ids),
+                        health)
                     if degraded:
                         lat_ms, arrived = edge32, False
                     elif raw:
@@ -394,6 +412,7 @@ class HybridEngine:
                 ll = l_logits[:, 0]
         if aslot is not None:
             self.adapters.release(aslot)
+        self.telemetry.merge(health)
         return TOK.decode(out_ids), stats
 
 
@@ -500,6 +519,8 @@ class _Lane:
         # with the last emitted token pending in ``lt``), so position
         # bookkeeping that unparks rows must restore the offset depth
         self._spec = use_cloud and bool(getattr(engine, "spec_k", 0))
+        self._boundary_key = ("boundaries_cloud" if use_cloud
+                              else "boundaries_edge")
         if getattr(engine, "paged", False):
             self.pager_s = engine._make_pager(engine.dep.slm, batch)
             if use_cloud:
@@ -590,16 +611,21 @@ class _Lane:
         of the running scan, and the scatter is dispatched against the
         macro's OUTPUT caches."""
         eng = self.eng
-        dep = eng.dep
         if not jobs:
             return
         if eng.paged:
             self._admit_paged(jobs)
             return
-        if not eng.packed_prefill:
-            for j in jobs:
-                self._admit_one(*j)
-            return
+        with eng.telemetry.span("floe.prefill_packed"):
+            if not eng.packed_prefill:
+                for j in jobs:
+                    self._admit_one(*j)
+            else:
+                self._admit_dense(jobs)
+
+    def _admit_dense(self, jobs: List[Tuple]):
+        eng = self.eng
+        dep = eng.dep
         n = len(jobs)
         raw = [TOK.encode(p + " ") for _, p, *_ in jobs]
         caps = [eng.max_seq - mn - 1 for _, _, mn, *_ in jobs]
@@ -760,13 +786,16 @@ class _Lane:
                 key = None if j.entry is None else id(j.entry)
                 by_key.setdefault(key, []).append(j)
             groups = list(by_key.values())
+        tel = eng.telemetry
         for group in groups:
-            if group[0].entry is None:
-                self._admit_paged_full(group)
-            else:
-                self._admit_paged_suffix(group, group[0].entry)
+            with tel.span("floe.prefill_packed"):
+                if group[0].entry is None:
+                    self._admit_paged_full(group)
+                else:
+                    self._admit_paged_suffix(group, group[0].entry)
         for j in wide:
-            self._admit_paged_chunked(j)
+            with tel.span("floe.prefill_chunked", rid=j.rid):
+                self._admit_paged_chunked(j)
 
     def _finish_admit(self, j: _PagedJob):
         """Install the slot bookkeeping for an admitted paged job —
@@ -932,6 +961,7 @@ class _Lane:
         those positions."""
         eng = self.eng
         dep = eng.dep
+        tel = eng.telemetry
         ps = dep.page_size
         W = eng.chunk_width
         ids = j.ids
@@ -940,63 +970,68 @@ class _Lane:
         # eng.lora is None too; every chunk call below passes eng.lora
         # with THIS gates_row, so the bank is never un-gated
         # ---- chunk 0: B=1 prefix build, whole-page pool freeze
-        toks0 = jnp.asarray([ids[:W]], jnp.int32)
-        hist_s = dep.slm_build_prefix(eng.slm_params, toks0, eng.lora,
-                                      gates_row)
-        if self.s_cache is None:
-            self._alloc(eng.slm.cfg.vocab_size,
-                        None if gates_row is None
-                        else gates_row.shape[-1])
-        content = eng.slm.prefix_page_rows(hist_s, W, ps, eng.max_seq)
-        self.s_cache = dep.insert_slm_prefix(
-            self.s_cache, content,
-            jnp.asarray(j.rows_s.full[:W // ps], jnp.int32))
-        hist_l = None
-        if self.use_cloud:
-            hist_l = dep.llm_build_prefix(eng.llm_params, toks0)
-            content_l = eng.llm.prefix_page_rows(hist_l, W, ps,
-                                                 eng.max_seq)
-            self.l_cache = dep.insert_llm_prefix(
-                self.l_cache, content_l,
-                jnp.asarray(j.rows_l.full[:W // ps], jnp.int32))
+        with tel.span("floe.chunk", rid=j.rid):
+            toks0 = jnp.asarray([ids[:W]], jnp.int32)
+            hist_s = dep.slm_build_prefix(eng.slm_params, toks0, eng.lora,
+                                          gates_row)
+            if self.s_cache is None:
+                self._alloc(eng.slm.cfg.vocab_size,
+                            None if gates_row is None
+                            else gates_row.shape[-1])
+            content = eng.slm.prefix_page_rows(hist_s, W, ps, eng.max_seq)
+            self.s_cache = dep.insert_slm_prefix(
+                self.s_cache, content,
+                jnp.asarray(j.rows_s.full[:W // ps], jnp.int32))
+            hist_l = None
+            if self.use_cloud:
+                hist_l = dep.llm_build_prefix(eng.llm_params, toks0)
+                content_l = eng.llm.prefix_page_rows(hist_l, W, ps,
+                                                     eng.max_seq)
+                self.l_cache = dep.insert_llm_prefix(
+                    self.l_cache, content_l,
+                    jnp.asarray(j.rows_l.full[:W // ps], jnp.int32))
         # ---- middle chunks: exact width, one dispatch per chunk
         pre = W
         while len(ids) - pre > W:
-            toks = jnp.asarray([ids[pre:pre + W]], jnp.int32)
-            lens = jnp.asarray([W], jnp.int32)
-            _, rows_s, hist_s = dep.slm_prefill_chunk(
-                eng.slm_params, toks, lens, hist_s, eng.lora,
-                gates_row, pre)
-            self._insert_chunk("s", rows_s, j.slot, j.rows_s, pre, W)
-            if self.use_cloud:
-                _, rows_l, hist_l = dep.llm_prefill_chunk(
-                    eng.llm_params, toks, lens, hist_l, pre)
-                self._insert_chunk("l", rows_l, j.slot, j.rows_l,
-                                   pre, W)
+            with tel.span("floe.chunk", rid=j.rid):
+                toks = jnp.asarray([ids[pre:pre + W]], jnp.int32)
+                lens = jnp.asarray([W], jnp.int32)
+                _, rows_s, hist_s = dep.slm_prefill_chunk(
+                    eng.slm_params, toks, lens, hist_s, eng.lora,
+                    gates_row, pre)
+                self._insert_chunk("s", rows_s, j.slot, j.rows_s, pre, W)
+                if self.use_cloud:
+                    _, rows_l, hist_l = dep.llm_prefill_chunk(
+                        eng.llm_params, toks, lens, hist_l, pre)
+                    self._insert_chunk("l", rows_l, j.slot, j.rows_l,
+                                       pre, W)
             pre += W
         # ---- final ragged chunk: ring/local + pos + decode logits
-        w = len(ids) - pre
-        wpad = PAG.pages_for(w, ps) * ps
-        toks = np.zeros((1, wpad), np.int32)
-        toks[0, :w] = ids[pre:]
-        toks_j = jnp.asarray(toks)
-        lens = jnp.asarray([w], jnp.int32)
-        s_logits, rows_s = dep.slm_prefill_suffix(
-            eng.slm_params, toks_j, lens, hist_s, eng.lora, gates_row,
-            pre, pre)
-        self._insert_chunk("s", rows_s, j.slot, j.rows_s, pre, wpad,
-                           last=True)
-        src = jnp.zeros((1,), jnp.int32)
-        dst = jnp.asarray([j.slot], jnp.int32)
-        self.sl = dep.insert_row(self.sl, s_logits[:, 0], src, dst)
-        if self.use_cloud:
-            l_logits, rows_l = dep.llm_prefill_suffix(
-                eng.llm_params, toks_j, lens, hist_l, pre, pre)
-            self._insert_chunk("l", rows_l, j.slot, j.rows_l, pre,
-                               wpad, last=True)
-            self.ll = dep.insert_row(self.ll, l_logits[:, 0], src, dst)
-        if gates_row is not None:
-            self.gates = dep.insert_row(self.gates, gates_row, src, dst)
+        with tel.span("floe.chunk", rid=j.rid):
+            w = len(ids) - pre
+            wpad = PAG.pages_for(w, ps) * ps
+            toks = np.zeros((1, wpad), np.int32)
+            toks[0, :w] = ids[pre:]
+            toks_j = jnp.asarray(toks)
+            lens = jnp.asarray([w], jnp.int32)
+            s_logits, rows_s = dep.slm_prefill_suffix(
+                eng.slm_params, toks_j, lens, hist_s, eng.lora, gates_row,
+                pre, pre)
+            self._insert_chunk("s", rows_s, j.slot, j.rows_s, pre, wpad,
+                               last=True)
+            src = jnp.zeros((1,), jnp.int32)
+            dst = jnp.asarray([j.slot], jnp.int32)
+            self.sl = dep.insert_row(self.sl, s_logits[:, 0], src, dst)
+            if self.use_cloud:
+                l_logits, rows_l = dep.llm_prefill_suffix(
+                    eng.llm_params, toks_j, lens, hist_l, pre, pre)
+                self._insert_chunk("l", rows_l, j.slot, j.rows_l, pre,
+                                   wpad, last=True)
+                self.ll = dep.insert_row(self.ll, l_logits[:, 0], src, dst)
+            if gates_row is not None:
+                self.gates = dep.insert_row(self.gates, gates_row, src,
+                                            dst)
+        tel.add("prefill_chunks", 2 + (pre - W) // W)
         self._finish_admit(j)
 
     def _insert_chunk(self, which: str, rows, slot: int, rowpages,
@@ -1036,10 +1071,10 @@ class _Lane:
     def _cancel_row(self, i: int, s: _Slot) -> Tuple[int, str, GenStats]:
         """Cancel an occupied row whose simulated clock passed its
         deadline: partial text surfaces with ``cancelled`` set, the
-        adapter pin drops.  The caller parks/releases the device row."""
+        adapter pin drops.  The caller parks/releases the device row and
+        counts the cancellation."""
         st = s.stats
         st.cancelled = True
-        self.eng._health["cancellations"] += 1
         self.eng._release_adapter(s)
         self.slots[i] = None
         return (s.rid, TOK.decode(s.out_ids), st)
@@ -1055,7 +1090,6 @@ class _Lane:
             if s.deadline_ms is not None \
                     and s.stats.clock_ms >= s.deadline_ms:
                 s.stats.cancelled = True
-                self.eng._health["cancellations"] += 1
                 self.eng._release_adapter(s)
                 out.append((s.rid, TOK.decode(s.out_ids), s.stats))
             else:
@@ -1070,6 +1104,8 @@ class _Lane:
                 freed.append(i)
         if freed:
             self._park_rows(freed)
+        if out:
+            self.eng.telemetry.add("cancellations", len(out))
         return out
 
     # ------------------------------------------------------------- decode
@@ -1113,16 +1149,18 @@ class _Lane:
                 degraded = np.zeros((b,), bool)
                 raws = np.zeros((b,), bool)
                 edge32, fb32 = eng._fault_f32()
+                health = Counter()
                 for i, s in enumerate(self.slots):
                     if s is None or s.parked:
                         continue
                     deg, raw = eng._mirror_breaker(
-                        s, bool(lost_h[i]), len(s.out_ids))
+                        s, bool(lost_h[i]), len(s.out_ids), health)
                     degraded[i], raws[i] = deg, raw
                     if deg:
                         lat[i] = edge32
                     elif raw:
                         lat[i] = fb32
+                eng.telemetry.merge(health)
                 arrived = OPS.cloud_arrival_mask(ok, occ, raws,
                                                  degraded=degraded)
             else:
@@ -1317,7 +1355,6 @@ class _Lane:
             ups_s.append((i, need - g_s + t, pid))
         for t, pid in enumerate(got_l):
             ups_l.append((i, need - g_l + t, pid))
-        self.eng._stat["grown_pages"] += len(got_s) + len(got_l)
         return True
 
     def _provision(self, k: int) -> List[Tuple[int, str, GenStats]]:
@@ -1342,11 +1379,13 @@ class _Lane:
         if not eng.paged or not eng.lazy_pages:
             return []
         forced: List[Tuple[int, str, GenStats]] = []
+        tally = Counter()
         while True:
             order = sorted(
                 (i for i, s in enumerate(self.slots) if s is not None),
                 key=lambda i: self.slots[i].seq)
             if not order:
+                eng.telemetry.merge(tally)
                 return forced
             ups_s: List[Tuple[int, int, int]] = []
             ups_l: List[Tuple[int, int, int]] = []
@@ -1363,15 +1402,18 @@ class _Lane:
                 elif not s.parked:
                     s.parked = True
                     pos_ups.append((i, ATT.FREED_POS))
-                    eng._stat["parks"] += 1
+                    tally["parks"] += 1
+            tally["grown_pages"] += len(ups_s) + len(ups_l)
             self._apply_growth("s", ups_s)
             if self.use_cloud:
                 self._apply_growth("l", ups_l)
             self._set_positions(pos_ups)
             if any_active:
+                eng.telemetry.merge(tally)
                 return forced
             if len(order) > 1:
                 self._evict(order[-1])      # youngest first
+                tally["evictions"] += 1
                 continue
             i = order[0]
             s = self.slots[i]
@@ -1379,7 +1421,7 @@ class _Lane:
             eng._release_adapter(s)
             self.slots[i] = None
             self._release_rows([i])
-            eng._stat["forced"] += 1
+            tally["forced"] += 1
 
     def _evict(self, i: int):
         """Release a parked row's pages and queue its request for
@@ -1390,7 +1432,6 @@ class _Lane:
         self.slots[i] = None
         self._release_rows([i])
         self._evictq.append(s)
-        self.eng._stat["evictions"] += 1
 
     def _readmit_evicted(self):
         """Re-admit evicted requests, oldest first, into freed slots/
@@ -1446,13 +1487,38 @@ class _Lane:
         flight."""
         eng = self.eng
         dep = eng.dep
-        if self._inflight is not None:
+        if self._inflight is not None or not (self.active or self._evictq):
             return
+        tel = eng.telemetry
+        with tel.span("floe.prepare"):
+            args = self._macro_args(k)
+        if args is None:
+            return
+        fn = dep.macro_cloud if self.use_cloud else dep.macro_edge
+        with tel.span("floe.enqueue"):
+            carry, traces = fn(*args)
+        self.s_cache, self.l_cache, self.sl, self.ll = carry[:4]
+        self._inflight = (k, traces)
+
+    def _boundary_prep(self, k: int):
+        """The host work of a lane boundary before its decode: deadline
+        cancels, re-admission of evicted rows, page growth for the next
+        ``k`` writes."""
+        tel = self.eng.telemetry
         self._pending_done.extend(self._cancel_expired())
-        self._readmit_evicted()
-        self._pending_done.extend(self._provision(k))
+        with tel.span("floe.readmit"):
+            self._readmit_evicted()
+        with tel.span("floe.provision"):
+            self._pending_done.extend(self._provision(k))
+
+    def _macro_args(self, k: int) -> Optional[Tuple]:
+        """Boundary host work and the macro-step's arguments, device
+        arrays included; None when no row is left to decode."""
+        eng = self.eng
+        dep = eng.dep
+        self._boundary_prep(k)
         if self.active == 0:
-            return
+            return None
         b = self.batch
         rids = np.zeros((b,), np.int32)
         keys = np.zeros((b,), np.int32)
@@ -1480,18 +1546,14 @@ class _Lane:
             greedy[i] = s.greedy
             bfails[i], bcool[i] = s.bfails, s.bcool
         sample = bool((~greedy & ~done).any())
-        fn = dep.macro_cloud if self.use_cloud else dep.macro_edge
-        carry, traces = fn(
-            eng.slm_params, eng.llm_params if self.use_cloud else None,
-            dep.mlp if self.use_cloud else None,
-            eng.lora, self._decode_gates(),
-            self.s_cache, self.l_cache, self.sl, self.ll,
-            jnp.asarray(bfails), jnp.asarray(bcool),
-            jnp.asarray(rids), jnp.asarray(keys), jnp.asarray(steps),
-            jnp.asarray(maxn), jnp.asarray(greedy), jnp.asarray(done),
-            k, sample)
-        self.s_cache, self.l_cache, self.sl, self.ll = carry[:4]
-        self._inflight = (k, traces)
+        return (eng.slm_params, eng.llm_params if self.use_cloud else None,
+                dep.mlp if self.use_cloud else None,
+                eng.lora, self._decode_gates(),
+                self.s_cache, self.l_cache, self.sl, self.ll,
+                jnp.asarray(bfails), jnp.asarray(bcool),
+                jnp.asarray(rids), jnp.asarray(keys), jnp.asarray(steps),
+                jnp.asarray(maxn), jnp.asarray(greedy), jnp.asarray(done),
+                k, sample)
 
     def macro_collect(self) -> List[Tuple[int, str, GenStats]]:
         """The ONE host sync of an in-flight macro-step: fetch the
@@ -1506,14 +1568,23 @@ class _Lane:
             return out_done
         k, traces = self._inflight
         self._inflight = None
-        toks, arrived, lat, w, emit, lost = eng.dep.fetch_traces(traces)
-        fault = eng.fault if self.use_cloud else None
+        tel = eng.telemetry
+        with tel.span("floe.sync"):
+            toks, arrived, lat, w, emit, lost = eng.dep.fetch_traces(traces)
+        with tel.span("floe.replay"):
+            return self._macro_replay(k, toks, arrived, lat, w, emit, lost)
 
+    def _macro_replay(self, k, toks, arrived, lat, w, emit,
+                      lost) -> List[Tuple[int, str, GenStats]]:
+        eng = self.eng
+        fault = eng.fault if self.use_cloud else None
         out_done: List[Tuple[int, str, GenStats]] = []
         out_done.extend(self._pending_done)
         self._pending_done = []
         freed: List[int] = []
         cancelled: List[int] = []
+        rows = self._rows_before_replay()
+        health = Counter()
         for t in range(k):
             for i, s in enumerate(self.slots):
                 if s is None or not emit[t, i]:
@@ -1535,7 +1606,7 @@ class _Lane:
                     # scan's active mask, so the mirror sees exactly
                     # the transitions the device carry integrated
                     deg, _ = eng._mirror_breaker(s, bool(lost[t, i]),
-                                                 len(s.out_ids))
+                                                 len(s.out_ids), health)
                 if self.use_cloud:
                     st.cloud_tokens += int(arrived[t, i])
                     st.fallback_tokens += int(not arrived[t, i])
@@ -1561,7 +1632,27 @@ class _Lane:
             # drained rows were parked in-scan; now return their pages
             # (dense rows stay parked-but-resident until re-admission)
             self._release_rows(freed)
+        health["cancellations"] += len(cancelled)
+        health[self._boundary_key] += 1
+        self._record_tokens(rows, health)
         return out_done
+
+    def _rows_before_replay(self):
+        """(rows, tokens they hold, rows without a token yet), taken
+        before a replay for ``_record_tokens``."""
+        rows = [s for s in self.slots if s is not None]
+        return (rows, sum(len(s.out_ids) for s in rows),
+                [s for s in rows if not s.out_ids])
+
+    def _record_tokens(self, before, tally: Counter):
+        """Merge a boundary's counts at once: the caller's tally, the
+        tokens emitted since ``before`` and the requests whose first
+        token they hold."""
+        rows, n0, fresh = before
+        tel = self.eng.telemetry
+        tally["tokens_emitted"] += sum(len(s.out_ids) for s in rows) - n0
+        tel.merge(tally)
+        tel.first_token([s.rid for s in fresh if s.out_ids], clock_ns())
 
     def macro_step(self, k: int) -> List[Tuple[int, str, GenStats]]:
         """Dispatch + collect in one call: decode K tokens for every
@@ -1585,7 +1676,7 @@ class _Lane:
             idx[t], val[t] = i, v
         return dep.set_row_pos(cache, jnp.asarray(idx), jnp.asarray(val))
 
-    def _spec_seed(self):
+    def _spec_seed(self, health: Counter):
         """Move freshly admitted (and eviction-resumed) rows onto the
         speculative protocol invariant: SLM at depth p = prompt_len + n
         with ``sl`` predicting emit n, LLM ONE BEHIND at depth p-1 with
@@ -1643,7 +1734,7 @@ class _Lane:
             edge32, fb32 = eng._fault_f32()
             for i in fresh:
                 deg, raw = eng._mirror_breaker(self.slots[i],
-                                               bool(lost_h[i]), 0)
+                                               bool(lost_h[i]), 0, health)
                 degraded[i], raws[i] = deg, raw
                 if deg:
                     lat[i] = edge32
@@ -1674,6 +1765,7 @@ class _Lane:
             st = s.stats
             if s.deadline_ms is not None and st.clock_ms >= s.deadline_ms:
                 self._pending_done.append(self._cancel_row(i, s))
+                health["cancellations"] += 1
                 freed.append(i)
                 continue
             st.cloud_tokens += int(arrived[i])
@@ -1734,17 +1826,40 @@ class _Lane:
         single ``fetch_traces`` sync."""
         eng = self.eng
         dep = eng.dep
-        if self._inflight is not None:
+        if self._inflight is not None or not (self.active or self._evictq):
             return
-        self._pending_done.extend(self._cancel_expired())
-        self._readmit_evicted()
+        tel = eng.telemetry
+        with tel.span("floe.prepare"):
+            args = self._spec_args(n_bursts, k)
+        if args is None:
+            return
+        head, (rids, keys, maxn, greedy), sample, carry = args
+        bursts = []
+        with tel.span("floe.enqueue"):
+            for _ in range(n_bursts):
+                s_c, l_c, sl, lt, fails, cool, steps, done = carry
+                carry, traces = dep.spec_cloud(
+                    *head, s_c, l_c, sl, lt, fails, cool,
+                    rids, keys, steps, maxn, greedy, done, k, sample)
+                bursts.append(traces)
+        self.s_cache, self.l_cache, self.sl, self.lt = carry[:4]
+        self._inflight = ("spec", k, bursts)
+
+    def _spec_args(self, n_bursts: int, k: int) -> Optional[Tuple]:
+        """Boundary host work, the seed tokens of fresh rows, and the
+        burst chain's arguments as (params, per-row constants, sample,
+        first carry); None when no row is left."""
+        eng = self.eng
+        dep = eng.dep
         # +1: the host-side seed token of a fresh row consumes one
         # provisioned write before the bursts even start
-        self._pending_done.extend(self._provision(n_bursts * k + 1))
+        self._boundary_prep(n_bursts * k + 1)
         if self.active:
-            self._spec_seed()
+            before, health = self._rows_before_replay(), Counter()
+            self._spec_seed(health)
+            self._record_tokens(before, health)
         if self.active == 0:
-            return
+            return None
         b = self.batch
         rids = np.zeros((b,), np.int32)
         keys = np.zeros((b,), np.int32)
@@ -1765,24 +1880,14 @@ class _Lane:
             greedy[i] = s.greedy
             bfails[i], bcool[i] = s.bfails, s.bcool
         sample = bool((~greedy & ~done).any())
-        gates = self._decode_gates()
-        s_c, l_c, sl, lt = self.s_cache, self.l_cache, self.sl, self.lt
-        fails_d, cool_d = jnp.asarray(bfails), jnp.asarray(bcool)
-        steps_d, done_d = jnp.asarray(steps), jnp.asarray(done)
-        rids_d, keys_d = jnp.asarray(rids), jnp.asarray(keys)
-        maxn_d, greedy_d = jnp.asarray(maxn), jnp.asarray(greedy)
-        bursts = []
-        for _ in range(n_bursts):
-            carry, traces = dep.spec_cloud(
-                eng.slm_params, eng.llm_params, dep.mlp, eng.lora, gates,
-                s_c, l_c, sl, lt, fails_d, cool_d,
-                rids_d, keys_d, steps_d, maxn_d, greedy_d, done_d,
-                k, sample)
-            (s_c, l_c, sl, lt, fails_d, cool_d,
-             steps_d, done_d) = carry
-            bursts.append(traces)
-        self.s_cache, self.l_cache, self.sl, self.lt = s_c, l_c, sl, lt
-        self._inflight = ("spec", k, bursts)
+        return ((eng.slm_params, eng.llm_params, dep.mlp, eng.lora,
+                 self._decode_gates()),
+                (jnp.asarray(rids), jnp.asarray(keys), jnp.asarray(maxn),
+                 jnp.asarray(greedy)),
+                sample,
+                (self.s_cache, self.l_cache, self.sl, self.lt,
+                 jnp.asarray(bfails), jnp.asarray(bcool),
+                 jnp.asarray(steps), jnp.asarray(done)))
 
     def spec_collect(self) -> List[Tuple[int, str, GenStats]]:
         """The ONE host sync of an in-flight burst chain: fetch every
@@ -1802,7 +1907,14 @@ class _Lane:
             return out_done
         _tag, k, bursts = self._inflight
         self._inflight = None
-        fetched = dep.fetch_traces(bursts)
+        tel = eng.telemetry
+        with tel.span("floe.sync"):
+            fetched = dep.fetch_traces(bursts)
+        with tel.span("floe.replay"):
+            return self._spec_replay(k, fetched)
+
+    def _spec_replay(self, k, fetched) -> List[Tuple[int, str, GenStats]]:
+        eng = self.eng
         fault = eng.fault
         edge32, _ = eng._fault_f32()
         out_done: List[Tuple[int, str, GenStats]] = []
@@ -1810,6 +1922,8 @@ class _Lane:
         self._pending_done = []
         freed: List[int] = []
         cancelled: List[int] = []
+        rows = self._rows_before_replay()
+        health = Counter()
         for (sels, n_emit, c_sel, arrived, lat, w, lost) in fetched:
             for i, s in enumerate(self.slots):
                 if s is None or not n_emit[i]:
@@ -1823,7 +1937,7 @@ class _Lane:
                 deg = False
                 if fault is not None:
                     deg, _raw = eng._mirror_breaker(
-                        s, bool(lost[i]), len(s.out_ids))
+                        s, bool(lost[i]), len(s.out_ids), health)
                 st.spec_drafted += k
                 st.spec_accepted += int(min(n_emit[i], c_sel[i]))
                 st.cloud_calls += int(not deg)
@@ -1833,7 +1947,7 @@ class _Lane:
                     # degraded too (pure SLM drafting, zero cloud cost)
                     extra = int(n_emit[i]) - 1
                     st.degraded_tokens += extra
-                    eng._health["degraded_tokens"] += extra
+                    health["degraded_tokens"] += extra
                 for t in range(int(n_emit[i])):
                     if s.deadline_ms is not None \
                             and st.clock_ms >= s.deadline_ms:
@@ -1860,6 +1974,9 @@ class _Lane:
             self._park_rows(cancelled)
         if freed and eng.paged:
             self._release_rows(freed)
+        health["cancellations"] += len(cancelled)
+        health[self._boundary_key] += 1
+        self._record_tokens(rows, health)
         return out_done
 
 
@@ -1994,7 +2111,10 @@ class BatchedHybridEngine(HybridEngine):
         # slot-gather kernel instead of the dense one-hot einsum
         self.use_slot_kernel = use_slot_kernel
         self._seq = 0
-        self._stat = dict(grown_pages=0, parks=0, evictions=0, forced=0)
+        self.telemetry.declare(GROWTH_KEYS + BOUNDARY_KEYS)
+        # requests the latest add_requests call refused softly: with a
+        # live row, they keep the engine loaded
+        self._soft_refused = 0
         self._rejected: List[Tuple[int, str]] = []
         self.cloud_lane = _Lane(self, batch_size, use_cloud=True)
         self.edge_lane = _Lane(self, edge_batch_size or batch_size,
@@ -2008,7 +2128,7 @@ class BatchedHybridEngine(HybridEngine):
     def growth_stats(self) -> Dict[str, int]:
         """Lazy-growth counters: pages grown at boundaries, rows parked
         for backpressure, evictions, forced completions."""
-        return dict(self._stat)
+        return self.telemetry.view(GROWTH_KEYS)
 
     def _make_pager(self, lm, batch: int) -> PAG.LanePager:
         """Host page bookkeeping for one (lane, model).  Default pool
@@ -2069,7 +2189,7 @@ class BatchedHybridEngine(HybridEngine):
             return True, None
         aslot = self.adapters.acquire(aid)
         if aslot is None:
-            blocked[private] = True
+            blocked[private] = "refused_fifo"
             return False, None
         return True, aslot
 
@@ -2085,13 +2205,42 @@ class BatchedHybridEngine(HybridEngine):
         Returns per-request admitted flags; soft-refused requests (lane
         full / free pages short / adapter slots all pinned) should be
         resubmitted later, hard rejects land in ``pop_rejected``."""
-        if self.paged:
-            return self._add_requests_paged(reqs)
+        tel = self.telemetry
+        t0 = clock_ns()
+        n_rej = len(self._rejected)
+        tel.offered([r[3] for r in reqs], t0)
+        tally = Counter(offers=len(reqs))
+        with tel.span("floe.admit"):
+            with tel.span("floe.gate"):
+                gate = (self._gate_paged if self.paged
+                        else self._gate_dense)
+                flags, jobs = gate(reqs, tally)
+            self.edge_lane.admit_many(jobs[True])
+            self.cloud_lane.admit_many(jobs[False])
+        t1 = clock_ns()
+        tally["admitted"] = sum(flags)
+        tally["hard_rejects"] = len(self._rejected) - n_rej
+        tel.merge(tally)
+        tel.admitted([r[3] for r, ok in zip(reqs, flags) if ok], t1)
+        tel.done([rid for rid, _ in self._rejected[n_rej:]], t1)
+        self._soft_refused = sum(tally[k] for k in REFUSAL_KEYS)
+        tel.mark_refused(self._soft_refused, t1)
+        loaded = self._holds_work()
+        tel.mark_load(loaded, t0 if loaded else t1)
+        return flags
+
+    def _holds_work(self) -> bool:
+        return bool(self.active_count() or self._soft_refused)
+
+    def _gate_dense(self, reqs: List[Tuple], tally: Counter):
+        """Dense admission gate: a free row per lane, adapter slots.
+        Returns (flags, per-lane admission jobs); counts soft refusals
+        by reason into ``tally``."""
         flags = [False] * len(reqs)
         jobs = {True: [], False: []}
         free = {True: self.edge_lane.free_slots(),
                 False: self.cloud_lane.free_slots()}
-        blocked = {True: False, False: False}
+        blocked = {True: None, False: None}
         for i, (prompt, max_new, greedy, rid, *rest) in enumerate(reqs):
             prefix = rest[1] if len(rest) > 1 else None
             aid = rest[2] if len(rest) > 2 else None
@@ -2103,9 +2252,11 @@ class BatchedHybridEngine(HybridEngine):
                 self._rejected.append((rid, self._adapter_reject_msg(aid)))
                 continue
             if blocked[private] or not free[private]:
+                tally[blocked[private] or "refused_lane_full"] += 1
                 continue
             ok, aslot = self._acquire_or_block(aid, blocked, private)
             if not ok:
+                tally["refused_adapter"] += 1
                 continue
             slot = free[private].pop(0)
             jobs[private].append((slot, full, max_new, greedy,
@@ -2113,11 +2264,9 @@ class BatchedHybridEngine(HybridEngine):
                                   rest[0] if rest else None, aslot,
                                   deadline))
             flags[i] = True
-        self.edge_lane.admit_many(jobs[True])
-        self.cloud_lane.admit_many(jobs[False])
-        return flags
+        return flags, jobs
 
-    def _add_requests_paged(self, reqs: List[Tuple]) -> List[bool]:
+    def _gate_paged(self, reqs: List[Tuple], tally: Counter):
         """Paged admission gate: free SLOT and free PAGES, per lane and
         per model.  Tokenization happens here (the gate needs page
         demands) and so does the page reservation — the prefill can
@@ -2132,13 +2281,16 @@ class BatchedHybridEngine(HybridEngine):
         the offending (model, demand, capacity); a soft refusal BLOCKS
         the lane for the rest of the burst — later arrivals must not
         overtake a waiting request (FIFO, no starvation), and a lane
-        with pending evictions admits nothing external at all."""
+        with pending evictions admits nothing external at all.  Returns
+        (flags, per-lane admission jobs); soft refusals are counted by
+        reason into ``tally``."""
         flags = [False] * len(reqs)
         jobs = {True: [], False: []}
         free = {True: self.edge_lane.free_slots(),
                 False: self.cloud_lane.free_slots()}
-        blocked = {True: bool(self.edge_lane._evictq),
-                   False: bool(self.cloud_lane._evictq)}
+        blocked = {p: "refused_evictq" if lane._evictq else None
+                   for p, lane in ((True, self.edge_lane),
+                                   (False, self.cloud_lane))}
         for i, (prompt, max_new, greedy, rid, *rest) in enumerate(reqs):
             seed = rest[0] if rest else None
             prefix = rest[1] if len(rest) > 1 else None
@@ -2188,6 +2340,7 @@ class BatchedHybridEngine(HybridEngine):
                     f"capacity {lane.pager_l.alloc.num_pages} pages")))
                 continue
             if blocked[private]:
+                tally[blocked[private]] += 1
                 continue                   # FIFO: no overtaking
             if self.lazy_pages:
                 nf_s, nl_s = lane.pager_s.demand_lazy(
@@ -2201,10 +2354,13 @@ class BatchedHybridEngine(HybridEngine):
                     or not lane.pager_s.fits_free(nf_s, nl_s) or (
                         lane.use_cloud
                         and not lane.pager_l.fits_free(nf_l, nl_l)):
-                blocked[private] = True    # soft: retry when pages free
+                tally["refused_pages" if free[private]
+                      else "refused_lane_full"] += 1
+                blocked[private] = "refused_fifo"  # soft: retry later
                 continue
             ok, aslot = self._acquire_or_block(aid, blocked, private)
             if not ok:                     # soft: retry when pins drop
+                tally["refused_adapter"] += 1
                 continue
             slot = free[private].pop(0)
             rows_s = lane.pager_s.admit(
@@ -2219,7 +2375,8 @@ class BatchedHybridEngine(HybridEngine):
                     lane.pager_s.release(slot)
             if rows_s is None or (lane.use_cloud and rows_l is None):
                 free[private].insert(0, slot)  # pragma: no cover
-                blocked[private] = True        # pragma: no cover
+                tally["refused_pages"] += 1    # pragma: no cover
+                blocked[private] = "refused_fifo"  # pragma: no cover
                 if aslot is not None:          # pragma: no cover
                     self.adapters.release(aslot)
                 continue
@@ -2228,9 +2385,7 @@ class BatchedHybridEngine(HybridEngine):
                 rows_s, rows_l, entry, seq=self._next_seq(),
                 truncated=truncated, aslot=aslot, deadline_ms=deadline))
             flags[i] = True
-        self.edge_lane.admit_many(jobs[True])
-        self.cloud_lane.admit_many(jobs[False])
-        return flags
+        return flags, jobs
 
     def pop_rejected(self) -> List[Tuple[int, str]]:
         """Drain the hard-reject log: (rid, reason) for requests whose
@@ -2312,6 +2467,13 @@ class BatchedHybridEngine(HybridEngine):
         """Sync + replay the in-flight macro-steps (or, with
         ``macro_k=0``, run one legacy per-token step).  Returns the
         requests that finished."""
+        out = self._collect()
+        t = clock_ns()
+        self.telemetry.done([rid for rid, _, _ in out], t)
+        self.telemetry.mark_load(self._holds_work(), t)
+        return out
+
+    def _collect(self) -> List[Tuple[int, str, GenStats]]:
         if self.macro_k:
             return (self.edge_lane.macro_collect()
                     + (self.cloud_lane.spec_collect() if self.spec_k

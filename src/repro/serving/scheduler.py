@@ -335,9 +335,6 @@ def summarize(responses: List[Response]) -> Dict[str, float]:
         "cloud_calls_per_token": float(np.mean(
             [r.stats.cloud_calls / max(1, r.stats.tokens)
              for r in responses])),
-        "cloud_used_frac": float(np.mean(
-            [r.stats.cloud_calls / max(1, r.stats.tokens)
-             for r in responses])),
         # speculative accept-rate over all responses (0.0 when the
         # engine never drafted)
         "accept_rate": float(accepted / max(1, drafted)),

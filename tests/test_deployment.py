@@ -307,6 +307,27 @@ def test_pipelined_admission_outputs_unchanged():
     _assert_bitexact(res_ref, res_macro)
 
 
+def test_entry_points_compile_under_their_own_names(parts_2b):
+    """Every jitted entry point carries its attribute's name, so its
+    XLA module is ``jit_<entry>`` and a profile names the program."""
+    import re
+    import jax.numpy as jnp
+    dep = _deployment(parts_2b, None)
+    entries = {n: getattr(dep, n) for n in dir(dep)
+               if not n.startswith("_")
+               and hasattr(getattr(dep, n), "lower")}
+    assert {"macro_cloud", "macro_edge", "spec_cloud", "insert_slm",
+            "insert_row", "grow_block_pages", "slm_prefill_chunk",
+            "sample_batched"} <= set(entries)
+    assert all(f.__name__ == n for n, f in entries.items())
+    probs = jnp.ones((2, 8)) / 8
+    for name, args in (("argmax_batched", (probs,)),
+                       ("sample_batched", (probs, jnp.zeros(2, jnp.int32),
+                                           jnp.zeros(2, jnp.int32)))):
+        text = getattr(dep, name).lower(*args).as_text()
+        assert re.search(r"module @(\S+)", text).group(1) == f"jit_{name}"
+
+
 # ----------------------------------------------------- subprocess fallback
 
 
