@@ -166,16 +166,22 @@ def rowwise_decode_attention(q, cache_k, cache_v, pos_b, window: int = 0):
 # Paged KV layout (serving/paging.py owns the host-side allocator)
 # ---------------------------------------------------------------------------
 #
-# A paged lane cache replaces each (B, S, KV, hd) leaf with a pool
-# (P, page_size, KV, hd) plus a per-row block table (B, n_pages): the
-# value at row position p lives at page table[b, p // page_size],
-# offset p % page_size.  Decode gathers each row's mapped pages back
-# into the dense rowwise layout and runs the IDENTICAL attention math,
-# so paged decode is bit-for-bit the dense path: extra gathered slots
-# are masked, masked scores hit NEG_INF, and exp underflows to exact
-# 0.0 in f32 — adding exact zeros never perturbs the reduction.
-# Unmapped table entries hold a sentinel far past the pool so writes
-# drop (mode="drop") and gathers clamp onto masked garbage.
+# A paged lane cache replaces each (*L, B, S, KV, hd) leaf (*L: its
+# stack's layer axes) with a pool (*L, P, page_size, KV, hd) plus a
+# per-row block table (B, n_pages): the value at row position p of
+# layer l lives at page table[b, p // page_size], offset p % page_size,
+# of pool[l].  Decode carries the
+# whole pool through the layer scan (models/model.py ``scan_layers``):
+# each layer, told its index, writes its one new token per row in place
+# with one scatter at [layer, page, offset], so no pool-sized copy is
+# rebuilt per token.  It then reads its layer's slice, gathers each
+# row's mapped pages back into the dense rowwise layout and runs the
+# IDENTICAL attention math, so paged decode is bit-for-bit the dense
+# path: extra gathered slots are masked, masked scores hit NEG_INF, and
+# exp underflows to exact 0.0 in f32 — adding exact zeros never
+# perturbs the reduction.  Unmapped table entries hold a sentinel far
+# past the pool so writes drop (mode="drop") and gathers clamp onto
+# masked garbage.
 
 
 def gather_pages(pool_flat, table, n_slots: int, page_size: int):
@@ -194,23 +200,26 @@ def gather_pages(pool_flat, table, n_slots: int, page_size: int):
 
 
 def scatter_page_token(pool, table, row_pos, slot, token_kv,
-                       slot_limit: int):
+                       slot_limit: int, layer):
     """Write one decode token per row into its mapped page.
 
-    pool: (P, page_size, ...); table: (B, n_pages); slot: (B,) in-row
-    slot index (absolute position for full-length leaves, pos % window
-    for ring leaves); token_kv: (B, ...).  Parked rows (row_pos >=
-    FREED_POS), slots past ``slot_limit`` (mirrors the dense scatter
-    dropping row_pos >= max_seq), and unmapped NO_PAGE entries all
-    produce an out-of-pool flat index, so the write drops instead of
-    corrupting a live page."""
-    p_pages, ps = pool.shape[0], pool.shape[1]
+    pool: (*L, P, page_size, ...) with one leading layer axis per entry
+    of ``layer``, the tuple of scalars that indexes the written layer;
+    table: (B, n_pages); slot: (B,) in-row slot index (absolute position
+    for full-length leaves, pos % window for ring leaves); token_kv:
+    (B, ...).  Parked rows (row_pos >= FREED_POS), slots past
+    ``slot_limit`` (mirrors the dense scatter dropping row_pos >=
+    max_seq), and unmapped NO_PAGE entries all produce an out-of-pool
+    flat index, so the write drops instead of corrupting a live page."""
+    n = len(layer)
+    p_pages, ps = pool.shape[n], pool.shape[n + 1]
     page_ix = jnp.minimum(slot // ps, table.shape[1] - 1)
     pid = jnp.take_along_axis(table, page_ix[:, None], axis=1)[:, 0]
     ok = (row_pos < FREED_POS) & (slot < slot_limit)
     flat = jnp.where(ok, pid * ps + slot % ps, p_pages * ps)
-    flat_pool = pool.reshape((p_pages * ps,) + pool.shape[2:])
-    out = flat_pool.at[flat].set(token_kv, mode="drop")
+    flat_pool = pool.reshape(pool.shape[:n] + (p_pages * ps,)
+                             + pool.shape[n + 2:])
+    out = flat_pool.at[layer + (flat,)].set(token_kv, mode="drop")
     return out.reshape(pool.shape)
 
 
@@ -281,9 +290,11 @@ def attention_block(cfg, p, x, *, positions, lora=None, gates=None,
 
     mode: "train" (no cache) | "prefill" (build cache) | "decode" (use+update).
     ``is_global``: for attn_type=="mixed"/"sliding", False -> windowed.
-    ``pages``: paged decode — cache leaves are pool slices (P, page_size,
-    KV, hd) and ``pages`` carries the block tables ({"block": (B, nb)}
-    plus {"local": (B, nl)} when ring/window leaves are paged).
+    ``pages``: paged decode — ``pages`` carries the block tables
+    ({"block": (B, nb)} plus {"local": (B, nl)} when ring/window leaves
+    are paged) and ``"layer"``, this layer's index (a tuple of scalars)
+    into the whole pools (*L, P, page_size, KV, hd) the cache leaves
+    then are.
     In prefill mode a ``cache`` holding {"k", "v", "hpos"} is a shared
     prefix HISTORY: queries attend over history + fresh KV (suffix
     prefill for COW prefix sharing) and only the fresh KV is returned.
@@ -344,13 +355,14 @@ def attention_block(cfg, p, x, *, positions, lora=None, gates=None,
             out = chunked_causal_attention(q, k, v, pos1d, pos1d, window)
         new_cache = {"k": k, "v": v}
     elif mode == "decode" and pages is not None:
-        # paged decode: cache leaves are pool slices (P, page_size, KV,
-        # hd).  Scatter the new token through the block table, gather
-        # the row's mapped pages back into the dense rowwise layout,
-        # and run the IDENTICAL rowwise attention — bit-for-bit the
-        # dense path (extra slots are masked to exact zero weight).
+        # paged decode (see "Paged KV layout" above): scatter the new
+        # token into this layer's pages of the pool, gather the row's
+        # mapped pages back into the dense rowwise layout, and run the
+        # IDENTICAL rowwise attention — bit-for-bit the dense path
+        # (extra slots are masked to exact zero weight).
         rp = row_pos if row_pos is not None else jnp.reshape(positions, (b,))
-        ps = cache["k"].shape[1]
+        layer = pages["layer"]
+        ps = cache["k"].shape[len(layer) + 1]
         local = pages.get("local")
         if window and local is not None:
             table, slot, n_slots = local, jnp.mod(rp, window), window
@@ -358,12 +370,15 @@ def attention_block(cfg, p, x, *, positions, lora=None, gates=None,
             table, slot = pages["block"], rp
             n_slots = pages["block"].shape[1] * ps
         ck = scatter_page_token(cache["k"], table, rp, slot, k[:, 0],
-                                n_slots)
+                                n_slots, layer)
         cv = scatter_page_token(cache["v"], table, rp, slot, v[:, 0],
-                                n_slots)
-        flat = lambda a: a.reshape((a.shape[0] * ps,) + a.shape[2:])
-        gk = gather_pages(flat(ck), table, n_slots, ps)
-        gv = gather_pages(flat(cv), table, n_slots, ps)
+                                n_slots, layer)
+
+        def rows(pool):
+            a = pool[layer]
+            a = a.reshape((a.shape[0] * ps,) + a.shape[2:])
+            return gather_pages(a, table, n_slots, ps)
+        gk, gv = rows(ck), rows(cv)
         if window and local is not None:
             out = rowwise_ring_decode_attention(q, gk, gv, rp, window)
         else:

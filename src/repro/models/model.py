@@ -487,19 +487,39 @@ class LM:
                 fn, policy=jax.checkpoint_policies.nothing_saveable
             ) if remat else fn
 
-        def scan_layers(body, x, stack_p, stack_c, stack_l, length):
-            """Scan `body` over stacked params (+cache xs, +lora xs)."""
+        def scan_layers(body, x, stack_p, stack_c, stack_l, length,
+                        lead=()):
+            """Scan `body` over stacked params (+cache xs, +lora xs).
+
+            Paged decode carries the page pools instead of scanning
+            them: each layer gets its index (``lead`` + its place in
+            this stack) and writes its token into the carried pool in
+            place, so no pool-sized ``ys`` is rebuilt every token."""
+            unroll = length if (self.unroll_layers and length) else 1
+            aux0 = jnp.zeros((), jnp.float32)
+            if pages is not None and stack_c is not None:
+                if not length:       # an empty tail: nothing to index
+                    return x, stack_c, aux0
+
+                def fp(carry, xs):
+                    xx, aux, pool = carry
+                    p_i, i, l_i = xs
+                    xx, pool, a = body(xx, p_i, pool, l_i, lead + (i,))
+                    return (xx, aux + a, pool), None
+                (x, aux, new_c), _ = jax.lax.scan(
+                    fp, (x, aux0, stack_c),
+                    (stack_p, jnp.arange(length), stack_l), length=length,
+                    unroll=unroll)
+                return x, new_c, aux
+
             def f(carry, xs):
                 xx, aux = carry
                 p_i, c_i, l_i = xs
                 xx, nc, a = body(xx, p_i, c_i, l_i)
                 return (xx, aux + a), nc
             xs = (stack_p, stack_c, stack_l)
-            (x, aux), new_c = jax.lax.scan(f, (x, jnp.zeros((), jnp.float32)),
-                                           xs, length=length,
-                                           unroll=length if
-                                           (self.unroll_layers and length)
-                                           else 1)
+            (x, aux), new_c = jax.lax.scan(f, (x, aux0), xs, length=length,
+                                           unroll=unroll)
             return x, new_c, aux
 
         def no_cache(n):
@@ -507,11 +527,12 @@ class LM:
 
         # ------- bodies ---------------------------------------------------
         def dense_body(is_global=True):
-            def body(xx, p_i, c_i, l_i):
+            def body(xx, p_i, c_i, l_i, layer=None):
+                pg = pages if layer is None else dict(pages, layer=layer)
                 return wrap(lambda a, b, c, d: dense_layer(
                     cfg, b, a, positions=positions, mode=mode, cache=c,
                     lora=d, gates=gates, is_global=is_global, absorb=absorb,
-                    pages=pages)
+                    pages=pg)
                 )(xx, p_i, c_i, l_i)
             return body
 
@@ -582,23 +603,36 @@ class LM:
 
             aux_total = jnp.zeros((), jnp.float32)
 
+            # paged decode: the group scan carries the pools too, each
+            # layer indexed by (group, place in the group)
+            paged = pages is not None and inner_c is not None
+
             def group_step(carry, xs):
-                xx, aux = carry
-                in_p, sp_p, in_c, sp_c, in_l, sp_l = xs
+                xx, aux, pools = carry
+                in_p, sp_p, in_c, sp_c, in_l, sp_l, gi = xs
+                lead = None
+                if paged:
+                    (in_c, sp_c), lead = pools, (gi,)
                 xx, nic, a1 = scan_layers(inner_body, xx, in_p, in_c, in_l,
-                                          g - 1)
+                                          g - 1, lead or ())
                 sp = special_params if is_hybrid else sp_p
-                xx, nsc, a2 = special_body(xx, sp, sp_c, sp_l)
-                return (xx, aux + a1 + a2), (nic, nsc)
+                xx, nsc, a2 = special_body(xx, sp, sp_c, sp_l, lead)
+                if paged:
+                    return (xx, aux + a1 + a2, (nic, nsc)), None
+                return (xx, aux + a1 + a2, None), (nic, nsc)
 
             sp_p_stack = None if is_hybrid else params["global_layers"]
             in_l = (lget.get("inner"))
             sp_l = (lget.get("special"))
-            (x, aux_total), (new_in_c, new_sp_c) = jax.lax.scan(
-                group_step, (x, aux_total),
-                (params["inner"], sp_p_stack, inner_c, special_c, in_l, sp_l),
+            pools = (inner_c, special_c) if paged else None
+            xs_c = (None, None) if paged else (inner_c, special_c)
+            (x, aux_total, pools), ys = jax.lax.scan(
+                group_step, (x, aux_total, pools),
+                (params["inner"], sp_p_stack, *xs_c, in_l, sp_l,
+                 jnp.arange(n_groups)),
                 length=n_groups,
                 unroll=n_groups if self.unroll_layers else 1)
+            new_in_c, new_sp_c = pools if paged else ys
             # tail (length may be 0 — lax.scan handles the empty stack)
             tl = lget.get("tail")
             x, new_tail_c, a3 = scan_layers(inner_body, x, params["tail"],

@@ -7,6 +7,7 @@ test_sharded_lanes)."""
 import os
 import subprocess
 import sys
+from dataclasses import replace
 from pathlib import Path
 
 import jax
@@ -156,6 +157,95 @@ def test_paged_matches_dense_ring(gemma_engine_parts):
     r_paged = _run_sched(_engine(gemma_engine_parts, True), reqs,
                          n_tokens=8)
     _assert_same(r_dense, r_paged)
+
+
+# Model-level parity: LM.decode_step on a paged cache against dense
+# decode, over hand-built block tables (page_size 4, max_seq 32, ring
+# window 16).  Row 0 crosses a page boundary (positions 2-5); row 1
+# crosses one and wraps its ring (14-17); both have NO_PAGE tails.  Row
+# 2 is parked at FREED_POS with its pages still mapped.
+NO = PAG.NO_PAGE
+_TABLES = {"block": [[5, 0, NO, NO, NO, NO, NO, NO],
+                     [1, 6, 7, 3, 8, NO, NO, NO],
+                     [4, 11, NO, NO, NO, NO, NO, NO]],
+           "local": [[5, 0, NO, NO], [2, 9, 4, 10], [6, 7, NO, NO]]}
+_POS0 = [2, 14, FREED_POS]
+
+
+def _kv_leaves(cache, axes):
+    """Each KV leaf of a cache tree, in sorted key order, with its
+    (batch or page, seq or slot) axes moved to the front."""
+    def by_key(t):
+        return {k: by_key(t[k]) for k in sorted(t)} \
+            if isinstance(t, dict) else t
+    out = []
+    PAG.walk_kv(by_key(cache), axes, lambda leaf, ab: out.append(
+        np.moveaxis(np.asarray(leaf), (ab, ab + 1), (0, 1))) or leaf)
+    return out
+
+
+def _table(leaf, max_seq):
+    return _TABLES["block" if leaf.shape[1] == max_seq else "local"]
+
+
+@pytest.mark.parametrize("arch,ring", [("floe-slm-2b", False),
+                                       ("floe-slm-gemma3", True),
+                                       ("granite-moe-3b-a800m", False)],
+                         ids=["plain", "grouped-ring", "moe"])
+def test_decode_step_paged_matches_dense(arch, ring):
+    """The paged decode step (pools carried through the layer scan, each
+    token written in place at [layer, page, offset]) is dense decode bit
+    for bit: the live rows' logits at every step, and afterwards every
+    page of the pool holds what the dense cache does, with the parked
+    row's pages, the NO_PAGE tails and the unmapped pages untouched."""
+    cfg = get_config(arch).reduced()
+    if ring:              # two groups of (local, global) and a local tail
+        cfg = replace(cfg, num_layers=5)
+    lm = LM(cfg, remat=False, ring_cache=ring)
+    params = lm.init(jax.random.key(0))
+    max_seq, ps, n_pages, steps, b = 32, 4, 12, 4, len(_POS0)
+    rng = np.random.default_rng(0)
+    axes = lm.cache_batch_axes_tree(max_seq)
+    dense = PAG.walk_kv(
+        lm.init_cache(b, max_seq), axes,
+        lambda leaf, ab: jnp.asarray(rng.standard_normal(leaf.shape),
+                                     leaf.dtype))
+    dense["pos"] = jnp.asarray(_POS0, jnp.int32)
+
+    def pool_of(leaf, ab):
+        # each dense row at its table's pages, noise on every other page
+        x = np.moveaxis(np.asarray(leaf), (ab, ab + 1), (0, 1))
+        pool = rng.standard_normal((n_pages, ps) + x.shape[2:]) \
+            .astype(x.dtype)
+        for row, pids in enumerate(_table(x, max_seq)):
+            for i, pid in enumerate(pids):
+                if pid != NO:
+                    pool[pid] = x[row, i * ps:(i + 1) * ps]
+        return jnp.asarray(np.moveaxis(pool, (0, 1), (ab, ab + 1)))
+
+    paged = PAG.walk_kv(dense, axes, pool_of)
+    paged["block"] = jnp.asarray(_TABLES["block"], jnp.int32)
+    if ring:
+        paged["local"] = jnp.asarray(_TABLES["local"], jnp.int32)
+    pools0 = _kv_leaves(paged, axes)
+    step = jax.jit(lm.decode_step)
+    for _ in range(steps):
+        tok = jnp.asarray(rng.integers(3, cfg.vocab_size, (b, 1)),
+                          jnp.int32)
+        ld, dense = step(params, dense, tok)
+        lp, paged = step(params, paged, tok)
+        np.testing.assert_array_equal(np.asarray(ld[:2]),
+                                      np.asarray(lp[:2]))
+    np.testing.assert_array_equal(np.asarray(paged["pos"]),
+                                  np.asarray(dense["pos"]))
+    for p0, p1, x in zip(pools0, _kv_leaves(paged, axes),
+                         _kv_leaves(dense, axes), strict=True):
+        want, tab = p0.copy(), _table(x, max_seq)
+        for row, start in enumerate(_POS0[:2]):
+            for pos in range(start, start + steps):
+                slot = pos % x.shape[1]
+                want[tab[row][slot // ps], slot % ps] = x[row, slot]
+        np.testing.assert_array_equal(p1, want)
 
 
 # ------------------------------------------------- lazy growth (ISSUE 7)
